@@ -92,6 +92,8 @@ def main(argv=None) -> None:
                     help="also write derived metrics as JSON (the CI "
                          "regression-gate artifact)")
     args = ap.parse_args(argv if argv is not None else sys.argv[1:])
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     # benchmarks.common reads these at import time, so set them before the
     # experiment imports below
     if args.scenario:

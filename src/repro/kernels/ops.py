@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-`flash_attention` here accepts the model-layout tensors
-(B, S, H, D) and handles transposition + CPU fallback:
-on a CPU backend Pallas-TPU cannot lower, so kernels run in interpret mode
-when `interpret=None` (auto) and the backend is CPU.
+The wrappers take model-layout tensors (B, S, H, D) and transpose them for
+the kernels. On a TPU they lower to Mosaic. Interpret mode, where the
+kernel body runs in Python, exists only for the CPU tests: `interpret=None`
+(auto) picks it when the backend is the CPU, because Pallas-TPU cannot
+lower there.
 """
 from __future__ import annotations
 
@@ -37,16 +38,15 @@ def _auto_interpret(interpret: Optional[bool]) -> bool:
     return bool(interpret)
 
 
-def flash_attention(q, k, v, *, mask=None, causal: bool = True,
-                    window: int = 0, q_offset: int = 0, scale: float = 1.0,
+def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
+                    q_offset: int = 0, scale: float = 1.0,
                     interpret: Optional[bool] = None):
     """Model-layout flash attention. q: (B, Sq, Hq, D); k/v: (B, Sk, Hkv, D).
 
-    `mask` is accepted for API-compatibility with the jnp path but must be
-    expressible as (causal, window, q_offset) — the kernel computes masking
-    from block iota, it never materializes an (Sq, Sk) mask.
+    The kernel computes its mask from block iota and (causal, window,
+    q_offset); it takes no explicit (Sq, Sk) mask, so a caller that holds
+    one must use the jnp path.
     """
-    del mask
     qt = jnp.swapaxes(q, 1, 2)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)

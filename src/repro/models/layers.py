@@ -339,20 +339,18 @@ def attn_op(q, k, v, *, causal: bool, window: int, scale: float,
         mask = mask & (kj <= qi)
     if window > 0:
         mask = mask & (kj > qi - window)
-    return sdpa(q, k, v, mask[None, None, None], scale, ctx)
+    return sdpa(q, k, v, mask[None, None, None], scale)
 
 
-def sdpa(q, k, v, mask, scale: float, ctx: ParallelContext):
+def sdpa(q, k, v, mask, scale: float):
     """Reference scaled-dot-product attention with GQA head grouping.
 
     q: (B, Sq, Hq, D); k, v: (B, Sk, Hkv, D); mask: broadcastable to
-    (B, Hkv, G, Sq, Sk) or (B, 1, 1, Sq, Sk). Swapped for the Pallas flash
-    kernel on TPU via ``repro.kernels.ops.flash_attention`` when
-    ``ctx.use_pallas``.
+    (B, Hkv, G, Sq, Sk) or (B, 1, 1, Sq, Sk). Plain jnp on every backend:
+    the Pallas flash kernel computes its mask from (causal, window,
+    q_offset) and cannot take an arbitrary one, so ``attn_op`` dispatches
+    to it before a mask exists.
     """
-    if ctx.use_pallas:
-        from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, mask=mask, scale=scale)
     b, sq, hq, d = q.shape
     hkv = k.shape[2]
     g = hq // hkv
@@ -414,7 +412,6 @@ def _decode_cp(q, cache, new_k, new_v, pos, window, scale,
     its slot (if owned), computes partial (m, l, acc) and the shards combine
     with a numerically-stable logsumexp reduction (pmax + psum).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b = q.shape[0]
@@ -466,11 +463,11 @@ def _decode_cp(q, cache, new_k, new_v, pos, window, scale,
     bspec = ctx.batch_spec if b % ctx.batch_size_divisor == 0 else None
     qspec = P(bspec, None, None, None)
     cspec = P(bspec, m_axis, None, None)
-    out, kc, vc = shard_map(
+    out, kc, vc = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(qspec, cspec, cspec, qspec, qspec, P()),
         out_specs=(qspec, cspec, cspec),
-        check_rep=False,
+        check_vma=False,
     )(q, cache["k"], cache["v"], new_k, new_v, pos)
     return out, {"k": kc, "v": vc}
 
@@ -555,7 +552,14 @@ def attention_layer(p, x, *, cfg: ModelConfig, ctx: ParallelContext,
         newk = update_cache_seq(cache["k"], k, slot)
         newv = update_cache_seq(cache["v"], v, slot)
         new_cache = {"k": newk, "v": newv}
-        if ctx.use_pallas and getattr(pos, "ndim", 0) == 0:
+        if ctx.use_pallas:
+            if getattr(pos, "ndim", 0) != 0:
+                raise NotImplementedError(
+                    "Pallas decode with per-slot positions needs a decode "
+                    "kernel that takes a per-slot valid_len; "
+                    "kernels.decode_attention takes one valid_len for the "
+                    "whole batch. Decode per-slot positions with "
+                    "use_pallas=False.")
             from repro.kernels import ops as kops
             vl = (jnp.minimum(pos + 1, window)
                   if window and window <= cache_len else pos + 1)
@@ -566,7 +570,7 @@ def attention_layer(p, x, *, cfg: ModelConfig, ctx: ParallelContext,
             valid = (ki < jnp.minimum(posv + 1, window)
                      if window and window <= cache_len else ki <= posv)
             mask = valid[:, None, None, None, :]  # (B,Hkv,G,Sq,Sk) bcast
-            out = sdpa(q, newk, newv, mask, scale, ctx)
+            out = sdpa(q, newk, newv, mask, scale)
     else:
         raise ValueError(mode)
 

@@ -21,7 +21,21 @@ from repro.models.parallel import ParallelContext
 # ---------------------------------------------------------------------------
 
 
-def init_params(key, cfg: ModelConfig):
+def init_params(key, cfg: ModelConfig, device=None):
+    """Random parameters of `cfg`, made by one jitted program.
+
+    Each leaf is drawn in float32 and cast to `cfg.dtype` inside that
+    program, so no float32 copy of a weight stack is ever held: a model
+    whose `cfg.dtype` parameters fill most of a chip still initializes on
+    it. With `device` the parameters are made on (and committed to) that
+    device; otherwise on JAX's default device.
+    """
+    if device is not None:
+        key = jax.device_put(key, device)
+    return _init_params_jit(key, cfg)
+
+
+def _init_params(key, cfg: ModelConfig):
     dt = jnp.dtype(cfg.dtype)
     k_embed, k_stack, k_enc, k_out = jax.random.split(key, 4)
     params = {
@@ -41,6 +55,9 @@ def init_params(key, cfg: ModelConfig):
             jax.random.fold_in(key, 7), (cfg.d_model, cfg.d_model),
             jnp.float32) * 0.02).astype(dt)
     return params
+
+
+_init_params_jit = jax.jit(_init_params, static_argnames="cfg")
 
 
 def params_shapes(cfg: ModelConfig):

@@ -216,14 +216,13 @@ def moe_layer_ep_a2a(p, x, *, cfg: ModelConfig, ctx: ParallelContext,
     bspec = ctx.batch_spec if b % ctx.batch_size_divisor == 0 else None
     sspec = ctx.model_axis if s % m == 0 else None
     x_spec = P(bspec, sspec, None)
-    from jax.experimental.shard_map import shard_map
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(x_spec, P(), P(ctx.model_axis, None, None),
                   P(ctx.model_axis, None, None),
                   P(ctx.model_axis, None, None)),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
 
     if cfg.n_shared_experts:

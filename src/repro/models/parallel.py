@@ -20,7 +20,9 @@ class ParallelContext:
     batch_axes: Tuple[str, ...] = ("data",)   # ("pod","data") on multi-pod
     model_axis: Optional[str] = "model"
     cp_axis: Optional[str] = None   # context-parallel axis for long-KV decode
-    use_pallas: bool = False        # pallas kernels need a real TPU backend
+    # Pallas kernels lower to Mosaic on a TPU; on the CPU they run in
+    # interpret mode, which is for the CPU tests only
+    use_pallas: bool = False
     remat: bool = True              # activation checkpointing in train_step
     moe_expert_parallel: bool = False  # §Perf layout lever (EXPERIMENTS.md)
     moe_dispatch: str = "dense"        # dense | capacity (§Perf lever)

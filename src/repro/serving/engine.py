@@ -18,6 +18,10 @@ elsewhere. The per-slot compute view stays the dense jitted cache (pages
 are scattered/gathered at evict/resume only), which keeps paged and dense
 decoding bit-identical; `repro.kernels.paged_attention` is the kernel
 that decodes straight from such a pool on TPU.
+
+Each engine lives on one device (`device`, default the first): its params,
+dense cache and page pool are committed there, so the jitted steps run
+there and a fleet of engines can spread over a host's chips.
 """
 from __future__ import annotations
 
@@ -27,7 +31,6 @@ import time
 from typing import List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
@@ -81,14 +84,17 @@ class ServingEngine:
                  max_seq: int = 1024, ctx: Optional[ParallelContext] = None,
                  temperature: float = 0.0, seed: int = 0,
                  paged: bool = False, kv_blocks: Optional[int] = None,
-                 kv_block_tokens: int = 16, prefix_sharing: bool = True):
+                 kv_block_tokens: int = 16, prefix_sharing: bool = True,
+                 device: Optional[jax.Device] = None):
         self.cfg = cfg
-        self.params = params
+        self.device = device if device is not None else jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
         self.ctx = ctx or cpu_context()
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.temperature = temperature
-        self.cache = M.init_cache(cfg, max_batch, max_seq)
+        with jax.default_device(self.device):
+            self.cache = M.init_cache(cfg, max_batch, max_seq)
         self._axis = _batch_axis_tree(cfg, max_seq)
         self.positions = np.zeros(max_batch, np.int32)
         self.cur_tokens = np.zeros(max_batch, np.int32)
@@ -118,7 +124,7 @@ class ServingEngine:
                 else max_batch * (max_seq // kv_block_tokens)
             self.kv = PagedKVCache(cfg, n_blocks=n_blocks,
                                    block_tokens=kv_block_tokens,
-                                   max_seq=max_seq)
+                                   max_seq=max_seq, device=self.device)
 
         self._decode = jax.jit(
             lambda p, t, c, pos: M.decode_step(p, t, c, pos, cfg=cfg,
@@ -128,8 +134,10 @@ class ServingEngine:
         # compiles once per bucket, not once per prompt length; `last`
         # indexes the true final-token logits. Padded garbage keys occupy
         # slots >= plen but decode overwrites them sequentially before the
-        # position mask can ever reach them.
-        def _prefill_cache(p, batch, c, last):
+        # position mask can ever reach them. The one-slot cache is built
+        # inside the program, so it starts on the engine's device.
+        def _prefill_cache(p, batch, last):
+            c = M.init_cache(cfg, 1, max_seq)
             logits, new_cache, _ = M.forward(p, batch, cfg=cfg,
                                              ctx=self.ctx, mode="prefill",
                                              cache=c)
@@ -204,6 +212,10 @@ class ServingEngine:
             self._compiled_shapes.add(key)
             self.metrics.inc(f"engine.{kind}_compiles")
 
+    def _put(self, host_value):
+        """An int32 array of `host_value` on the engine's device."""
+        return jax.device_put(np.array(host_value, np.int32), self.device)
+
     def _insert_slot(self, slot: int, single_cache):
         def ins(pool, one, ax):
             return jax.lax.dynamic_update_slice_in_dim(pool, one, slot, ax)
@@ -257,10 +269,9 @@ class ServingEngine:
                 logits = None
                 self._note_compile("decode", (1, 1))
                 for i in range(skip, plen):
-                    tok = jnp.asarray([[req.prompt[i]]], jnp.int32)
                     logits, one_cache = self._decode(
-                        self.params, tok, one_cache,
-                        jnp.asarray([i], jnp.int32))
+                        self.params, self._put([[req.prompt[i]]]),
+                        one_cache, self._put([i]))
                 last_logits = logits
                 self.n_prefix_hits += 1
                 self.prefix_tokens_reused += skip
@@ -268,19 +279,18 @@ class ServingEngine:
                 bucket = 1 << max(plen - 1, 1).bit_length()  # next pow2 >= plen
                 bucket = min(bucket, self.max_seq)
                 padded = req.prompt + [0] * (bucket - plen)
-                prompt = jnp.asarray(padded, jnp.int32)[None, :]
-                one_cache = M.init_cache(self.cfg, 1, self.max_seq)
+                prompt = self._put([padded])
                 batch = {"tokens": prompt}
                 if self.cfg.mrope:
                     s = prompt.shape[1]
-                    batch["positions"] = jnp.broadcast_to(
-                        jnp.arange(s, dtype=jnp.int32), (3, 1, s))
+                    batch["positions"] = self._put(np.broadcast_to(
+                        np.arange(s, dtype=np.int32), (3, 1, s)))
                 # deliberate shape polymorphism: the pow-2 bucketing above
                 # caps this at log2(max_seq) distinct prefill shapes, and
                 # `engine.prefill_compiles` counts them at runtime
                 self._note_compile("prefill", prompt.shape)
                 last_logits, one_cache = self._prefill(  # repro-check: disable=R8
-                    self.params, batch, one_cache, jnp.int32(plen - 1))
+                    self.params, batch, self._put(plen - 1))
             self.n_prefills += 1
             if self._sharing:
                 # publish the prompt's full blocks while they really hold
@@ -363,8 +373,8 @@ class ServingEngine:
         active = self.active_slots
         if not active:
             return 0
-        tokens = jnp.asarray(self.cur_tokens, jnp.int32)[:, None]
-        pos = jnp.asarray(self.positions, jnp.int32)
+        tokens = self._put(self.cur_tokens[:, None])
+        pos = self._put(self.positions)
         self._note_compile("decode", tokens.shape)
         logits, self.cache = self._decode(self.params, tokens, self.cache,
                                           pos)

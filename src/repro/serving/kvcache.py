@@ -266,13 +266,16 @@ class PagedKVCache:
     """Block-pool storage for one engine's KV cache."""
 
     def __init__(self, cfg: ModelConfig, n_blocks: int, block_tokens: int,
-                 max_seq: int, dtype=None):
+                 max_seq: int, dtype=None, device: Optional[jax.Device] = None):
         if max_seq % block_tokens:
             raise ValueError(
                 f"block_tokens={block_tokens} must divide max_seq={max_seq}")
         self.cfg = cfg
         self.block_tokens = block_tokens
         self.max_seq = max_seq
+        # where the pools live; pages imported from another engine's pool
+        # and resumed snapshot state are moved here
+        self.device = device if device is not None else jax.devices()[0]
         self.allocator = BlockAllocator(n_blocks)
         # Probe which leaves carry the sequence axis: leaves whose shape
         # changes with max_seq are paged; the rest (recurrent states, conv
@@ -297,7 +300,8 @@ class PagedKVCache:
                 continue
             rest = a.shape[:axis] + a.shape[axis + 1:]
             self._pools.append(jnp.zeros(
-                (n_blocks, block_tokens) + rest, a.dtype))
+                (n_blocks, block_tokens) + rest, a.dtype,
+                device=self.device))
         # prefix sharing needs the pages to BE the whole per-request
         # state: any non-sequence leaf (SSM states, rolling windows)
         # carries history the pages can't reproduce for a different
@@ -457,8 +461,9 @@ class PagedKVCache:
     def import_pages(self, pages: List[Optional[Any]],
                      n_blocks: int) -> Optional[PageTable]:
         """Adopt migrated pages into this pool: allocate `n_blocks`
-        fresh blocks and scatter each exported leaf in. None under pool
-        exhaustion (the caller falls back to re-prefill)."""
+        fresh blocks, move each exported leaf to this pool's device and
+        scatter it in. None under pool exhaustion (the caller falls back
+        to re-prefill)."""
         ids = self._allocate_fresh(n_blocks)
         if ids is None:
             return None
@@ -466,26 +471,30 @@ class PagedKVCache:
         for i, leaf in enumerate(pages):
             if leaf is None:
                 continue
-            self._pools[i] = self._pools[i].at[arr].set(leaf)
+            self._pools[i] = self._pools[i].at[arr].set(
+                jax.device_put(leaf, self.device))
         return PageTable(blocks=ids, block_tokens=self.block_tokens)
 
     def load(self, table: PageTable, state: List[Any]):
         """Gather `table`'s pages back into a dense single-slot cache.
 
         Sequence positions past the table's span are zeros; decode masks
-        them by position exactly as it masks never-written tail slots."""
+        them by position exactly as it masks never-written tail slots.
+        State leaves are moved to this pool's device (a migrated snapshot
+        arrives from another engine's)."""
         ids = jnp.asarray(table.blocks, jnp.int32)
         flat: List[Any] = []
         state_it = iter(state)
         for i, axis in enumerate(self._seq_axis):
             if axis is None:
-                flat.append(next(state_it))
+                flat.append(jax.device_put(next(state_it), self.device))
                 continue
             pool = self._pools[i]
             pages = pool[ids]                       # (nb, bt, *rest)
             lead = pages.reshape((-1,) + pages.shape[2:])
             rest = pool.shape[2:]
-            full = jnp.zeros((self.max_seq,) + rest, pool.dtype)
+            full = jnp.zeros((self.max_seq,) + rest, pool.dtype,
+                             device=self.device)
             full = full.at[: lead.shape[0]].set(lead)
             flat.append(jnp.moveaxis(full, 0, axis))
         return jax.tree.unflatten(self._treedef, flat)

@@ -1,8 +1,9 @@
 import os
 import sys
 
-# Tests run on the real host device(s); only launch/dryrun forces 512.
-# Keep XLA quiet and deterministic.
+# Tests run on the CPU backend (Pallas kernels in interpret mode); the TPU
+# is reached only through chip_smoke.py. Subprocess tests force their own
+# host device counts.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))

@@ -45,7 +45,8 @@ def test_cp_decode_matches_plain():
                             cfg=cfg, ctx=ctx0)
 
         # CP: 2 data x 4 model; cache seq 32 % 4 == 0, kv_heads=1 % 4 != 0
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ('data', 'model'))
         ctx = ParallelContext(mesh=mesh, batch_axes=('data',),
                               model_axis='model')
         from repro.models.layers import kv_cache_cp
@@ -71,7 +72,8 @@ def test_moe_ep_a2a_matches_dense():
         from repro.models.parallel import ParallelContext, cpu_context
 
         cfg = get_config('deepseek-moe-16b').reduced()   # 4 experts, top-2
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ('data', 'model'))
         ctx = ParallelContext(mesh=mesh, batch_axes=('data',),
                               model_axis='model')
         key = jax.random.key(0)
@@ -104,7 +106,8 @@ def test_sharded_train_step_runs():
 
         cfg = get_config('gemma-2b').reduced(n_layers=2, d_model=128,
                                              vocab_size=512)
-        mesh = jax.make_mesh((2, 4), ('data', 'model'))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 4), ('data', 'model'))
         ctx = ParallelContext(mesh=mesh, batch_axes=('data',),
                               model_axis='model')
         params = init_params(jax.random.key(0), cfg)

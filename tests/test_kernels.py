@@ -12,7 +12,6 @@ from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ref import decode_attention_ref, flash_attention_ref
 from repro.models.layers import flash_jnp_call, sdpa
-from repro.models.parallel import cpu_context
 
 KEY = jax.random.key(42)
 
@@ -146,7 +145,6 @@ def test_paged_attention_ragged_valid_lengths():
 
 
 def test_flash_jnp_matches_sdpa():
-    ctx = cpu_context()
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (2, 1024, 4, 32), jnp.float32)
     k = jax.random.normal(ks[1], (2, 1024, 2, 32), jnp.float32)
@@ -159,14 +157,13 @@ def test_flash_jnp_matches_sdpa():
             mask = mask & (kj > qi - window)
         o1 = flash_jnp_call(q, k, v, causal=True, window=window, scale=0.2,
                             block_q=256, block_k=256)
-        o2 = sdpa(q, k, v, mask[None, None, None], 0.2, ctx)
+        o2 = sdpa(q, k, v, mask[None, None, None], 0.2)
         np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                    rtol=2e-4, atol=2e-4)
 
 
 def test_flash_jnp_custom_vjp_matches_autodiff():
     """FA2 manual backward == autodiff through the reference sdpa."""
-    ctx = cpu_context()
     ks = jax.random.split(KEY, 3)
     q = jax.random.normal(ks[0], (1, 512, 4, 32), jnp.float32)
     k = jax.random.normal(ks[1], (1, 512, 2, 32), jnp.float32)
@@ -181,8 +178,7 @@ def test_flash_jnp_custom_vjp_matches_autodiff():
             block_q=128, block_k=128)))
 
     def f2(q, k, v):
-        return jnp.sum(jnp.sin(sdpa(q, k, v, mask[None, None, None],
-                                    0.2, ctx)))
+        return jnp.sum(jnp.sin(sdpa(q, k, v, mask[None, None, None], 0.2)))
 
     g1 = jax.grad(f1, argnums=(0, 1, 2))(q, k, v)
     g2 = jax.grad(f2, argnums=(0, 1, 2))(q, k, v)
@@ -369,3 +365,22 @@ def test_auto_interpret_memoized_and_forced_lowering_error():
             ops._auto_interpret(False)
     else:
         assert ops._auto_interpret(False) is False
+
+
+def test_pallas_decode_with_slot_positions_raises():
+    """Decode under use_pallas with per-slot positions (what the engine
+    passes) raises instead of running the flash kernel with its mask
+    dropped; the kernel wrapper takes no explicit mask at all."""
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import cpu_context, decode_step, init_cache, init_params
+    cfg = get_config("gemma-2b").reduced()
+    params = init_params(jax.random.key(0), cfg)
+    ctx = cpu_context(remat=False, use_pallas=True)
+    toks = jnp.zeros((2, 1), jnp.int32)
+    with pytest.raises(NotImplementedError, match="per-slot valid_len"):
+        decode_step(params, toks, init_cache(cfg, 2, 64),
+                    jnp.asarray([3, 9], jnp.int32), cfg=cfg, ctx=ctx)
+    q = jnp.zeros((1, 8, 2, 64))
+    with pytest.raises(TypeError, match="mask"):
+        ops.flash_attention(q, q, q, mask=jnp.ones((8, 8), bool))

@@ -261,6 +261,63 @@ def test_migration_resumes_with_zero_reprefill_and_occupies_links():
     assert rt.kv_used == [0, 0]                     # ledger drains
 
 
+def test_engine_migration_lands_on_destination_device():
+    """Pages exported from an engine on one device and imported by an
+    engine on another land on the destination's device, and the resumed
+    request generates what an uninterrupted run does. Two virtual CPU
+    devices, so it runs in a subprocess."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    script = textwrap.dedent("""
+        import jax
+        from repro.configs import get_config
+        from repro.models import init_params
+        from repro.serving import ServingEngine
+        from repro.serving.engine import Request
+
+        cfg = get_config("gemma-2b").reduced(n_layers=2, d_model=128,
+                                             vocab_size=512)
+        d0, d1 = jax.devices()[:2]
+        params = init_params(jax.random.key(0), cfg)
+
+        def engine(dev):
+            return ServingEngine(cfg, params, max_batch=2, max_seq=64,
+                                 paged=True, kv_block_tokens=16, device=dev)
+
+        ref, src, dst = engine(d0), engine(d0), engine(d1)
+        prompt = list(range(3, 23))
+        ref.submit(prompt, max_new_tokens=8)
+        ref.run_until_idle()
+        req = src.submit(prompt, max_new_tokens=8)
+        src.step()
+        src.step()
+        old = src.evict(req.slot)
+        table = dst.kv.import_pages(src.kv.export(old.pages),
+                                    len(old.pages.blocks))
+        moved = Request(rid=0, prompt=list(old.prompt), max_new_tokens=8,
+                        generated=list(old.generated), pages=table,
+                        kv=old.kv)
+        old.kv = None
+        src.release(old)
+        dst.resubmit(moved)
+        dst.run_until_idle()
+        held = {d for leaf in jax.tree.leaves((dst.kv._pools, dst.cache))
+                for d in leaf.devices()}
+        assert held == {d1}, held
+        assert moved.generated == ref.completed[0].generated, (
+            moved.generated, ref.completed[0].generated)
+        print("MIGRATED OK")
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    r = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                       text=True, timeout=300, env=env)
+    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
+    assert "MIGRATED OK" in r.stdout
+
+
 def test_refused_migration_is_counted_not_silent():
     """Without `migrate_kv` the cross-server requeue abandons its pages:
     the drop is surfaced as `n_kv_orphaned` and the continuation pays the
