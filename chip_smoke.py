@@ -265,16 +265,16 @@ def phase_fleet(edge_cfg, cloud_cfg, *, n_edge=2, n_requests=12,
                        FLEET_MAX_NEW, deadline=FLEET_DEADLINE_S)
     wall_s = time.perf_counter() - t0
     stats = srv.stats
-    if stats["served"] != n_requests or not all(
+    if stats["n_served"] != n_requests or not all(
             sr.done and len(sr.engine_req.generated) == FLEET_MAX_NEW
             for sr in reqs):
-        raise RuntimeError(f"fleet served {stats['served']} of "
+        raise RuntimeError(f"fleet served {stats['n_served']} of "
                            f"{n_requests} requests")
     placed = [str(eng.device.id) for eng in srv.engines]
     print(f"{label}: policy={srv.scheduler.name} edge={edge_cfg.arch_id}"
           f"x{n_edge}@{edge_cfg.n_layers}L cloud={cloud_cfg.arch_id}"
           f"@{cloud_cfg.n_layers}L devices={','.join(placed)} "
-          f"served={stats['served']}/{n_requests} "
+          f"served={stats['n_served']}/{n_requests} "
           f"per_server={stats['per_server_served']} "
           f"preempted={stats['n_preempted']} wall_s={wall_s:.2f} "
           f"(compiles included)", flush=True)

@@ -65,7 +65,7 @@ from repro.core.runtime import (
     Arrival, BandwidthChange, EventLoop, InferDone, KvMigrate, Preempt,
     Reject, Runtime, Scenario, TxDone, make_scenario,
 )
-from repro.obs.metrics import MetricsRegistry, counter_attr, with_aliases
+from repro.obs.metrics import MetricsRegistry, counter_attr
 from repro.obs.trace import (
     KIND_ARRIVAL, KIND_DECISION, KIND_KV_WAIT, KIND_MIGRATE,
     KIND_PREEMPT, KIND_REJECT, KIND_RESUME,
@@ -135,9 +135,8 @@ class SimResult:
 
     def stats(self) -> Dict[str, object]:
         """Canonical-key stats dict (shared naming with
-        `PerLLMServer.stats` / `ServingEngine.stats`), with the
-        deprecated old-name aliases included for one release."""
-        return with_aliases({
+        `PerLLMServer.stats` / `ServingEngine.stats`)."""
+        return {
             "n_served": sum(self.per_server_served),
             "n_rejected": self.n_rejected,
             "n_preempted": self.n_preempted,
@@ -149,7 +148,7 @@ class SimResult:
             "avg_processing_time": self.avg_processing_time,
             "per_server_served": list(self.per_server_served),
             "served_tokens": self.served_tokens,
-        })
+        }
 
     @classmethod
     def empty(cls, name: str, n_servers: int) -> "SimResult":

@@ -279,9 +279,14 @@ class Runtime:
         view = self.build_view(ev.time)
         t_slot = ev.slot_index if ev.slot_index >= 0 \
             else self.slot_index(ev.time)
-        decisions = drive_slot(self.policy, ev.requests, view, t_slot)
+        decisions = self.decide(ev.requests, view, t_slot)
         for req, d in zip(ev.requests, decisions, strict=True):
             self.place(ev.time, req, d)
+
+    def decide(self, requests: Sequence[Any], view: ClusterView,
+               t_slot: int) -> List[Decision]:
+        """The policy's Decisions for one arrival batch (`drive_slot`)."""
+        return drive_slot(self.policy, requests, view, t_slot)
 
     def place(self, t: float, request, decision: Decision) -> None:
         """Apply one Decision: reject, preempt-then-dispatch, or defer.

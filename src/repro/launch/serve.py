@@ -110,13 +110,13 @@ def _run_fleet(args, edge_cfg: ModelConfig) -> None:
     dt = time.time() - t0
     s = srv.stats
     name = srv.scheduler.name
-    if not s["served"]:
+    if not s["n_served"]:
         print(f"{name}: served 0 requests in {dt:.1f}s")
         return
-    print(f"{name}: served {s['served']} requests in {dt:.1f}s — "
-          f"deadline_met={s['deadline_met']*100:.0f}% "
-          f"mean_latency={s['mean_latency']:.2f}s "
-          f"per_server={s['per_server']}")
+    print(f"{name}: served {s['n_served']} requests in {dt:.1f}s — "
+          f"deadline_met={s['admitted_success_rate']*100:.0f}% "
+          f"mean_latency={s['avg_processing_time']:.2f}s "
+          f"per_server={s['per_server_served']}")
 
 
 def _kv_kwargs(args) -> dict:

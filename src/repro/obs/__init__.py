@@ -7,9 +7,7 @@ and a traced run is result-bit-identical to an untraced one.
 from repro.obs.export import (
     perfetto_events, validate_perfetto, write_csv, write_perfetto,
 )
-from repro.obs.metrics import (
-    DEPRECATED_ALIASES, MetricsRegistry, counter_attr, with_aliases,
-)
+from repro.obs.metrics import MetricsRegistry, counter_attr
 from repro.obs.trace import (
     KIND_ARM, KIND_ARRIVAL, KIND_DECISION, KIND_DONE, KIND_INFER,
     KIND_KV_WAIT, KIND_MIGRATE, KIND_NAMES, KIND_PREEMPT, KIND_QUEUE,
@@ -17,10 +15,10 @@ from repro.obs.trace import (
 )
 
 __all__ = [
-    "DEPRECATED_ALIASES", "KIND_ARM", "KIND_ARRIVAL", "KIND_DECISION",
+    "KIND_ARM", "KIND_ARRIVAL", "KIND_DECISION",
     "KIND_DONE", "KIND_INFER", "KIND_KV_WAIT", "KIND_MIGRATE",
     "KIND_NAMES", "KIND_PREEMPT", "KIND_QUEUE", "KIND_REJECT",
     "KIND_RESUME", "KIND_TX", "MetricsRegistry", "SPAN_KINDS",
     "TraceRecorder", "counter_attr", "perfetto_events",
-    "validate_perfetto", "with_aliases", "write_csv", "write_perfetto",
+    "validate_perfetto", "write_csv", "write_perfetto",
 ]

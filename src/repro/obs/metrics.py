@@ -13,12 +13,6 @@ work unchanged while `SimResult` / `stats()` read straight out of the
 registry. The slot holds whatever Python number was assigned (int or
 float), so floating-point accumulation order — and therefore
 bit-identity with the pre-registry code — is preserved.
-
-Deprecated key aliases: the pre-unification stats dictionaries used a
-second naming convention (``served`` vs ``n_served``, ``prefix_hits`` vs
-``n_prefix_hits``). :data:`DEPRECATED_ALIASES` maps old → canonical and
-:func:`with_aliases` adds the old spellings back onto a canonical stats
-dict for one release; new code should read only canonical keys.
 """
 from __future__ import annotations
 
@@ -26,31 +20,6 @@ from bisect import bisect_right
 from typing import Dict, Iterable, List, Tuple
 
 import numpy as np
-
-#: old key -> canonical key. The old spellings are served by
-#: :func:`with_aliases` for one release and then removed.
-DEPRECATED_ALIASES = {
-    "served": "n_served",
-    "rejected": "n_rejected",
-    "preempted": "n_preempted",
-    "kv_migrations": "n_kv_migrations",
-    "prefix_hits": "n_prefix_hits",
-    "prefix_tokens_reused": "kv_prefill_tokens_saved",
-    "prefills": "n_prefills",
-    "deadline_met": "admitted_success_rate",
-    "mean_latency": "avg_processing_time",
-    "per_server": "per_server_served",
-}
-
-
-def with_aliases(stats: Dict[str, object]) -> Dict[str, object]:
-    """Return ``stats`` plus the deprecated old-name aliases for every
-    canonical key present."""
-    out = dict(stats)
-    for old, new in DEPRECATED_ALIASES.items():
-        if new in out and old not in out:
-            out[old] = out[new]
-    return out
 
 
 def _label_key(labels: dict) -> Tuple[Tuple[str, object], ...]:

@@ -48,9 +48,24 @@ energy    float64  energy attributed to the span (J); ``ARM``: reward
 value     float64  kind-specific payload (see ``KIND_VALUE_DOC``)
 aux       int32    interned label id (links/lanes), -1 n/a
 ========  =======  ====================================================
+
+Measured-clock spans. Beside the lifecycle rows (on a runtime's clock,
+modelled or simulated) the recorder keeps a second bounded table of
+spans and marks on one measured clock, ``time.time_ns()``: the wall
+clock of the serving engine's request stamps. The live server and its
+engines emit them (``begin``/``end``/``mark``); the simulators never do.
+Spans nest on one host thread: a span's parent is the innermost span
+still open when it begins. With :attr:`TraceRecorder.mirror` on, each
+span also opens a ``jax.profiler.TraceAnnotation`` of the same name, so
+in a profiled run the spans sit on the device trace's own clock.
+:meth:`TraceRecorder.spans` returns the surviving window as numpy
+columns ``name, span, parent, sid, server, t0_ns, t1_ns, value``
+(``t0_ns == t1_ns`` for a mark; ``span``/``parent`` are ids, -1 for no
+parent).
 """
 from __future__ import annotations
 
+import time
 from collections import deque
 from typing import Dict, List, Optional
 
@@ -99,6 +114,13 @@ _COLUMNS = (
 )
 
 
+_SPAN_COLUMNS = (
+    ("name", str), ("span", np.int64), ("parent", np.int64),
+    ("sid", np.int64), ("server", np.int32), ("t0_ns", np.int64),
+    ("t1_ns", np.int64), ("value", np.float64),
+)
+
+
 def _expand_completions(d: np.ndarray) -> np.ndarray:
     """Expand (m, 13) completion records into the (4m, 10) schema rows
     TX / QUEUE / INFER / DONE — all slice assignments, no Python loop
@@ -130,12 +152,15 @@ def _expand_completions(d: np.ndarray) -> np.ndarray:
 
 
 class TraceRecorder:
-    """Ring-buffered columnar store for lifecycle rows.
+    """Ring-buffered columnar store for lifecycle rows and measured-clock
+    spans.
 
     Pass one instance as ``trace=`` to ``Simulator.run`` /
     ``PerLLMServer`` (and optionally attach it to ``CSUCB.trace``); read
     it back with :meth:`to_arrays` or the exporters in
-    :mod:`repro.obs.export`.
+    :mod:`repro.obs.export`. The live server and its engines also write
+    spans (:meth:`begin`, :meth:`end`, :meth:`mark`), read back with
+    :meth:`spans`; the spans ring holds at most ``capacity`` of them.
     """
 
     def __init__(self, capacity: int = 1 << 18) -> None:
@@ -149,6 +174,12 @@ class TraceRecorder:
         self._label_ids: Dict[str, int] = {}
         self._mat: Optional[Dict[str, np.ndarray]] = None
         self._mat_stamp = -1
+        # measured-clock spans: closed spans and marks, the open stack
+        self._spans: deque = deque(maxlen=self.capacity)
+        self._open: List[tuple] = []
+        self._next_span = 0
+        self.n_spans = 0             # spans and marks recorded
+        self._annotation = None      # jax.profiler.TraceAnnotation if mirror
 
     # -- write path ----------------------------------------------------
     def append(self, kind: int, sid: int, t0: float, t1: float,
@@ -190,6 +221,85 @@ class TraceRecorder:
             self._label_ids[label] = lid
             self._labels.append(label)
         return lid
+
+    # -- measured-clock spans ------------------------------------------
+    @property
+    def mirror(self) -> bool:
+        """Whether spans begun now also open a profiler annotation."""
+        return self._annotation is not None
+
+    @mirror.setter
+    def mirror(self, on: bool) -> None:
+        if on:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        else:
+            self._annotation = None
+
+    def begin(self, name: str, sid: int = -1, server: int = -1) -> tuple:
+        """Open a span now; returns the token :meth:`end` takes. Its
+        parent is the innermost span still open."""
+        ann = self._annotation
+        if ann is not None:
+            ann = ann(name)
+            ann.__enter__()
+        parent = self._open[-1][1] if self._open else -1
+        tok = (name, self._next_span, parent, sid, server, time.time_ns(),
+               ann)
+        self._next_span += 1
+        self._open.append(tok)
+        return tok
+
+    def end(self, tok: tuple, value: float = 0.0) -> None:
+        """Close the span `tok` now (and any span opened inside it that
+        was left open) and record it."""
+        t1 = time.time_ns()
+        self._close(tok)
+        name, span, parent, sid, server, t0, _ = tok
+        self._spans.append((name, span, parent, sid, server, t0, t1, value))
+        self.n_spans += 1
+
+    def then(self, tok: tuple, name: str) -> tuple:
+        """End `tok` and begin its next sibling `name` under the same
+        request and server."""
+        self.end(tok)
+        return self.begin(name, tok[3], tok[4])
+
+    def discard(self, tok: tuple) -> None:
+        """Close the span `tok` without recording it (work that turned
+        out not to happen, e.g. an admission stalled on KV blocks)."""
+        self._close(tok)
+
+    def _close(self, tok: tuple) -> None:
+        while self._open:
+            top = self._open.pop()
+            if top[6] is not None:
+                top[6].__exit__(None, None, None)
+            if top is tok:
+                return
+
+    def mark(self, name: str, sid: int = -1, server: int = -1,
+             value: float = 0.0) -> None:
+        """Record an instant, under the innermost open span."""
+        t = time.time_ns()
+        parent = self._open[-1][1] if self._open else -1
+        self._spans.append((name, self._next_span, parent, sid, server, t, t,
+                            value))
+        self._next_span += 1
+        self.n_spans += 1
+
+    @property
+    def spans_dropped(self) -> int:
+        """Spans and marks that fell off the front of their ring."""
+        return self.n_spans - len(self._spans)
+
+    def spans(self) -> Dict[str, np.ndarray]:
+        """The surviving closed spans and marks as numpy columns, sorted
+        by ``(t0_ns, span)``: the one conversion, made on read."""
+        rows = sorted(self._spans, key=lambda r: (r[5], r[1]))
+        cols = list(zip(*rows)) if rows else [()] * len(_SPAN_COLUMNS)
+        return {name: np.array(col, dtype=dt)
+                for (name, dt), col in zip(_SPAN_COLUMNS, cols, strict=True)}
 
     def flush(self) -> None:
         """Materialize the columnar view now (optional — readers do this

@@ -22,6 +22,14 @@ that decodes straight from such a pool on TPU.
 Each engine lives on one device (`device`, default the first): its params,
 dense cache and page pool are committed there, so the jitted steps run
 there and a fleet of engines can spread over a host's chips.
+
+With a `repro.obs.TraceRecorder` attached (`attach_trace`), each step
+records measured-clock spans: `engine.run` around the step, one
+`engine.admission` per request admitted (with `engine.prefill`,
+`engine.first_token`, `engine.kv_store`, `engine.kv_load` and
+`engine.kv_insert` inside), then `engine.decode` (the decode and sampling
+dispatches), `engine.token_wait` (the host waiting for the sampled
+tokens; its end is every token's host time) and `engine.emit`.
 """
 from __future__ import annotations
 
@@ -35,7 +43,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
-from repro.obs.metrics import MetricsRegistry, counter_attr, with_aliases
+from repro.obs.metrics import MetricsRegistry, counter_attr
 from repro.models.parallel import ParallelContext, cpu_context
 from repro.serving.kvcache import KVSnapshot, PagedKVCache, PageTable
 from repro.serving.sampling import sample_tokens
@@ -47,6 +55,7 @@ class Request:
     prompt: List[int]
     max_new_tokens: int = 32
     eos_id: int = -1          # -1: never stop early
+    sid: int = -1             # the id spans carry; -1: the rid
     # runtime
     slot: int = -1
     generated: List[int] = dataclasses.field(default_factory=list)
@@ -57,6 +66,10 @@ class Request:
     # and the resume snapshot written by `evict` (consumed by re-admission)
     pages: Optional[PageTable] = None
     kv: Optional[KVSnapshot] = None
+
+    def __post_init__(self) -> None:
+        if self.sid < 0:
+            self.sid = self.rid
 
     @property
     def done(self) -> bool:
@@ -117,6 +130,10 @@ class ServingEngine:
         # decode-step pacing. Set via `set_freq_scale`.
         self.freq_scale = 1.0
         self.paged = paged
+        # optional repro.obs.TraceRecorder for measured-clock spans; every
+        # site is guarded by `if self.trace is not None`
+        self.trace = None
+        self._trace_server = -1
         self.kv: Optional[PagedKVCache] = None
         if paged:
             # default pool: the dense reservation's worth of blocks
@@ -126,9 +143,9 @@ class ServingEngine:
                                    block_tokens=kv_block_tokens,
                                    max_seq=max_seq, device=self.device)
 
-        self._decode = jax.jit(
-            lambda p, t, c, pos: M.decode_step(p, t, c, pos, cfg=cfg,
-                                               ctx=self.ctx))
+        def decode_step(p, t, c, pos):
+            return M.decode_step(p, t, c, pos, cfg=cfg, ctx=self.ctx)
+        self._decode = jax.jit(decode_step)
 
         # prompts are right-padded to power-of-2 buckets so prefill
         # compiles once per bucket, not once per prompt length; `last`
@@ -145,8 +162,17 @@ class ServingEngine:
         self._prefill = jax.jit(_prefill_cache)
 
     # ------------------------------------------------------------------
+    def attach_trace(self, trace, server: int = -1) -> None:
+        """Record spans into `trace` (None detaches), under the fleet's
+        index `server` for this engine."""
+        self.trace = trace
+        self._trace_server = server
+
     def submit(self, prompt: List[int], max_new_tokens: int = 32,
-               eos_id: int = -1) -> Request:
+               eos_id: int = -1, sid: int = -1) -> Request:
+        """Queue a request. `sid` is the id its spans carry (the caller's
+        request id); -1, as when the engine is used alone, means its
+        `rid`."""
         if self.paged:
             need = self.kv.blocks_for(len(prompt) + max_new_tokens)
             if need > self.kv.n_blocks:
@@ -154,7 +180,7 @@ class ServingEngine:
                     f"request needs {need} KV blocks but the pool only has "
                     f"{self.kv.n_blocks}; it could never be admitted")
         req = Request(rid=next(self._rid), prompt=list(prompt),
-                      max_new_tokens=max_new_tokens, eos_id=eos_id,
+                      max_new_tokens=max_new_tokens, eos_id=eos_id, sid=sid,
                       submitted_at=time.time())
         self.queue.append(req)
         return req
@@ -216,10 +242,15 @@ class ServingEngine:
         """An int32 array of `host_value` on the engine's device."""
         return jax.device_put(np.array(host_value, np.int32), self.device)
 
-    def _insert_slot(self, slot: int, single_cache):
+    def _insert_slot(self, slot: int, single_cache, sid: int) -> None:
         def ins(pool, one, ax):
             return jax.lax.dynamic_update_slice_in_dim(pool, one, slot, ax)
+        tr = self.trace
+        sp = tr.begin("engine.kv_insert", sid, self._trace_server) \
+            if tr is not None else None
         self.cache = jax.tree.map(ins, self.cache, single_cache, self._axis)
+        if sp is not None:
+            tr.end(sp)
 
     def _extract_slot(self, slot: int):
         def ext(pool, ax):
@@ -228,6 +259,7 @@ class ServingEngine:
 
     # ------------------------------------------------------------------
     def _admit(self) -> None:
+        tr = self.trace
         for slot in range(self.max_batch):
             if self.slot_req[slot] is not None:
                 continue
@@ -238,11 +270,15 @@ class ServingEngine:
                 self.queue.pop(0)
                 self._resume(slot, req)
                 continue
+            adm = tr.begin("engine.admission", req.sid, self._trace_server) \
+                if tr is not None else None
             if self.paged:
                 req.pages = self.kv.allocate(
                     len(req.prompt) + req.max_new_tokens,
                     prompt=req.prompt if self._sharing else None)
                 if req.pages is None:
+                    if adm is not None:
+                        tr.discard(adm)    # stalled: nothing admitted
                     # KV pressure: admission stalls FIFO — but a resumable
                     # continuation further back already holds its pages
                     # (it allocates nothing) and must pass the stalled
@@ -259,6 +295,8 @@ class ServingEngine:
             if self.paged and req.pages is not None \
                     and req.pages.shared_blocks > 0:
                 skip = req.pages.shared_blocks * self.kv.block_tokens
+            if adm is not None:
+                sp = tr.begin("engine.prefill", req.sid, self._trace_server)
             if skip > 0:
                 # prefix hit: the table's read-shared head already holds
                 # the prompt's first `skip` tokens of KV — gather the
@@ -296,29 +334,46 @@ class ServingEngine:
                 # publish the prompt's full blocks while they really hold
                 # its KV (pages are otherwise only written at eviction) so
                 # later admissions can attach them copy-on-write
+                if adm is not None:
+                    sp = tr.then(sp, "engine.kv_store")
                 self.kv.store_prefix(req.pages, one_cache, n_tokens=plen)
                 self.kv.register_prefix(req.prompt, req.pages)
+            if adm is not None:
+                sp = tr.then(sp, "engine.first_token")
             self._key, k = jax.random.split(self._key)
             tok = int(sample_tokens(k, last_logits, self.temperature)[0])
-            self._insert_slot(slot, one_cache)
+            if adm is not None:
+                tr.end(sp)
+            self._insert_slot(slot, one_cache, req.sid)
             req.slot = slot
             req.generated.append(tok)
             req.first_token_at = time.time()
             self.positions[slot] = len(req.prompt)
             self.cur_tokens[slot] = tok
             self.slot_req[slot] = req
+            if adm is not None:
+                tr.end(adm)
             self._maybe_finish(slot)
 
     def _resume(self, slot: int, req: Request) -> None:
         """Reattach an evicted request: gather its pages back into the
         slot's dense compute cache and continue decoding — no prefill."""
+        tr = self.trace
+        if tr is not None:
+            adm = tr.begin("engine.admission", req.sid, self._trace_server)
+            sp = tr.begin("engine.kv_load", req.sid, self._trace_server)
         snap = req.kv
         req.kv = None
-        self._insert_slot(slot, self.kv.load(req.pages, snap.state))
+        one_cache = self.kv.load(req.pages, snap.state)
+        if tr is not None:
+            tr.end(sp)
+        self._insert_slot(slot, one_cache, req.sid)
         req.slot = slot
         self.positions[slot] = snap.position
         self.cur_tokens[slot] = snap.cur_token
         self.slot_req[slot] = req
+        if tr is not None:
+            tr.end(adm)
         self._maybe_finish(slot)
 
     def evict(self, slot: int, keep_kv: bool = True) -> Optional[Request]:
@@ -369,30 +424,43 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def step(self) -> int:
         """Admit + one decode step for all active slots. Returns #active."""
+        tr = self.trace
+        if tr is not None:
+            run = tr.begin("engine.run", -1, self._trace_server)
         self._admit()
         active = self.active_slots
         if not active:
+            if tr is not None:
+                tr.end(run)
             return 0
+        if tr is not None:
+            sp = tr.begin("engine.decode", -1, self._trace_server)
         tokens = self._put(self.cur_tokens[:, None])
         pos = self._put(self.positions)
         self._note_compile("decode", tokens.shape)
         logits, self.cache = self._decode(self.params, tokens, self.cache,
                                           pos)
         self._key, k = jax.random.split(self._key)
-        next_tokens = np.asarray(sample_tokens(k, logits, self.temperature))
+        sampled = sample_tokens(k, logits, self.temperature)
+        if tr is not None:
+            sp = tr.then(sp, "engine.token_wait")
+        next_tokens = np.asarray(sampled)
+        if tr is not None:
+            sp = tr.then(sp, "engine.emit")
         for slot in active:
             req = self.slot_req[slot]
             req.generated.append(int(next_tokens[slot]))
             self.positions[slot] += 1
             self.cur_tokens[slot] = next_tokens[slot]
             self._maybe_finish(slot)
+        if tr is not None:
+            tr.end(sp)
+            tr.end(run, len(active))
         return len(active)
 
     def stats(self) -> dict:
         """Engine-local counters under the canonical key namespace shared
-        with ``PerLLMServer.stats`` / ``SimResult.stats()`` (old spellings
-        like ``prefills`` / ``prefix_tokens_reused`` ride along as
-        deprecated aliases for one release)."""
+        with ``PerLLMServer.stats`` / ``SimResult.stats()``."""
         out = {
             "n_prefills": self.n_prefills,
             "n_prefix_hits": self.n_prefix_hits,
@@ -404,7 +472,7 @@ class ServingEngine:
         if self.paged:
             out["kv_free_blocks"] = self.kv.free_blocks
             out["kv_total_blocks"] = self.kv.n_blocks
-        return with_aliases(out)
+        return out
 
     def run_until_idle(self, max_steps: int = 10_000) -> List[Request]:
         """Step until queue and slots drain. Raises if `max_steps` runs out

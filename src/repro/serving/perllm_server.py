@@ -20,12 +20,20 @@ clock, so a fast edge is never held hostage to the cloud's step time.
 Realized completions report the true transmission/queue/inference split and
 energy from the realized inference window, so the live learner's feedback
 matches the simulator's semantics.
+
+With a `repro.obs.TraceRecorder` attached (`trace=` or `attach_trace`),
+besides the lifecycle rows on the modelled clock the server records
+measured-clock spans and marks, each under the request's `sid`:
+`server.submit` and `server.dispatch` (into engine j) marks,
+`server.route` around an arrival's routing (view, policy, placement),
+`policy.assign` and `policy.feedback` around the policy's calls; its
+engines record theirs (`repro.serving.engine`).
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
-from typing import Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -39,12 +47,18 @@ from repro.core.runtime import (
     Runtime, TxDone,
 )
 from repro.core.scheduler import PerLLMScheduler
-from repro.obs.metrics import MetricsRegistry, counter_attr, with_aliases
+from repro.obs.metrics import MetricsRegistry, counter_attr
 from repro.obs.trace import (
     KIND_ARRIVAL, KIND_DECISION, KIND_MIGRATE, KIND_PREEMPT,
     KIND_REJECT, KIND_RESUME,
 )
 from repro.serving.engine import Request, ServingEngine
+
+
+def _batch_sid(requests) -> int:
+    """The sid a span over an arrival batch carries: its request's, or -1
+    for a batch of several."""
+    return requests[0].sid if len(requests) == 1 else -1
 
 
 @dataclasses.dataclass
@@ -93,12 +107,9 @@ class PerLLMServer(Runtime, LinkStateMixin):
         self.scheduler = scheduler or PerLLMScheduler(len(specs))
         super().__init__(self.scheduler, trace=trace)
         self.metrics = MetricsRegistry()
-        if trace is not None \
-                and getattr(self.scheduler, "bandit", None) is not None:
-            # the bandit stamps ARM rows into the same recorder
-            self.scheduler.bandit.trace = trace
         self.specs = list(specs)
         self.engines = list(engines)
+        self.attach_trace(trace)
         self.bandwidth = bandwidth or BandwidthModel()
         # the fleet's network: named links + per-server paths (defaults to
         # the degenerate one-private-link-per-server legacy model); link
@@ -143,6 +154,18 @@ class PerLLMServer(Runtime, LinkStateMixin):
         self.n_kv_migrations = 0
         self.kv_migrated_bytes = 0.0
 
+    def attach_trace(self, trace) -> None:
+        """Record into `trace` from now on (None detaches): lifecycle
+        rows, the bandit's arm pulls, and measured-clock spans here and
+        in every engine."""
+        self.trace = trace
+        if trace is not None \
+                and getattr(self.scheduler, "bandit", None) is not None:
+            # the bandit stamps ARM rows into the same recorder
+            self.scheduler.bandit.trace = trace
+        for j, eng in enumerate(self.engines):
+            eng.attach_trace(trace, j)
+
     # ------------------------------------------------------------------
     def submit(self, prompt: List[int], max_new_tokens: int = 16,
                deadline: float = 4.0,
@@ -157,6 +180,8 @@ class PerLLMServer(Runtime, LinkStateMixin):
         self._by_sid[svc.sid] = sr
         self._pending.append(sr)
         self.loop.push(Arrival(self.clock, requests=(svc,)))
+        if self.trace is not None:
+            self.trace.mark("server.submit", svc.sid)
         return sr
 
     # ------------------------------------------------------------------
@@ -262,6 +287,23 @@ class PerLLMServer(Runtime, LinkStateMixin):
     # ------------------------------------------------------------------
     # Event handlers: route -> transmit -> engine ticks -> finish
     # ------------------------------------------------------------------
+    def on_arrival(self, ev: Arrival) -> None:
+        if self.trace is None:
+            super().on_arrival(ev)
+            return
+        sp = self.trace.begin("server.route", _batch_sid(ev.requests))
+        super().on_arrival(ev)
+        self.trace.end(sp)
+
+    def decide(self, requests: Sequence[Any], view: ClusterView,
+               t_slot: int) -> List[Decision]:
+        if self.trace is None:
+            return super().decide(requests, view, t_slot)
+        sp = self.trace.begin("policy.assign", _batch_sid(requests))
+        decisions = super().decide(requests, view, t_slot)
+        self.trace.end(sp)
+        return decisions
+
     def place(self, t: float, svc: ServiceRequest,
               decision: Decision) -> None:
         sr = self._by_sid[svc.sid]
@@ -338,8 +380,12 @@ class PerLLMServer(Runtime, LinkStateMixin):
                 KIND_REJECT, svc.sid, ev.time, ev.time,
                 ev.decision.server if ev.decision is not None else -1,
                 svc.class_id)
+        if self.trace is not None:
+            sp = self.trace.begin("policy.feedback", svc.sid)
         self.policy.feedback(svc, rejected_outcome(svc, ev.decision,
                                                    ev.time))
+        if self.trace is not None:
+            self.trace.end(sp)
         self.rejected.append(sr)
 
     def on_preempt(self, ev: Preempt) -> None:
@@ -485,12 +531,12 @@ class PerLLMServer(Runtime, LinkStateMixin):
         if table is None:
             src.release(old_req)
             sr.engine_req = dst.submit(
-                sr._prompt, max_new_tokens=svc.output_tokens)
+                sr._prompt, max_new_tokens=svc.output_tokens, sid=svc.sid)
         else:
             new_req = Request(rid=next(dst._rid),
                               prompt=list(old_req.prompt),
                               max_new_tokens=old_req.max_new_tokens,
-                              eos_id=old_req.eos_id,
+                              eos_id=old_req.eos_id, sid=svc.sid,
                               generated=list(old_req.generated),
                               pages=table, kv=old_req.kv)
             old_req.kv = None        # the snapshot moved with the pages
@@ -500,6 +546,8 @@ class PerLLMServer(Runtime, LinkStateMixin):
             if self.trace is not None:
                 self.trace.append(KIND_RESUME, svc.sid, ev.time, ev.time,
                                   j, svc.class_id)
+        if self.trace is not None:
+            self.trace.mark("server.dispatch", svc.sid, j)
         self._ensure_tick(j, ev.time)
 
     def on_tx_done(self, ev: TxDone) -> None:
@@ -526,7 +574,10 @@ class PerLLMServer(Runtime, LinkStateMixin):
             return
         else:
             sr.engine_req = eng.submit(
-                sr._prompt, max_new_tokens=sr.service.output_tokens)
+                sr._prompt, max_new_tokens=sr.service.output_tokens,
+                sid=sr.service.sid)
+        if self.trace is not None:
+            self.trace.mark("server.dispatch", sr.service.sid, j)
         self._ensure_tick(j, ev.time)
 
     def _ensure_tick(self, j: int, t: float) -> None:
@@ -588,7 +639,10 @@ class PerLLMServer(Runtime, LinkStateMixin):
                            admit, sr.done_clock, sr.server,
                            svc.class_id, tier, -1, e_tx, e_inf,
                            svc.output_tokens, sr.met_deadline)
+            sp = trace.begin("policy.feedback", svc.sid)
         self.policy.feedback(sr.service, out)
+        if self.trace is not None:
+            self.trace.end(sp)
         self.completed.append(sr)
         del self.active[sr.service.sid]
         del self._by_sid[sr.service.sid]
@@ -621,18 +675,15 @@ class PerLLMServer(Runtime, LinkStateMixin):
     @property
     def stats(self) -> dict:
         """Canonical fleet stats (one key namespace with
-        ``SimResult.stats()``), plus the deprecated pre-unification
-        spellings via :func:`repro.obs.metrics.with_aliases` — old
-        readers of e.g. ``served`` / ``deadline_met`` keep working for
-        one release. The same values land in ``self.metrics``."""
+        ``SimResult.stats()``). The same values land in
+        ``self.metrics``."""
         done = self.completed
         m = self.metrics
         m.put_scalar("n_served", len(done))
         m.put_scalar("n_rejected", len(self.rejected))
         if not done:
-            return with_aliases({"n_served": 0,
-                                 "n_rejected": len(self.rejected),
-                                 "n_preempted": self.n_preempted})
+            return {"n_served": 0, "n_rejected": len(self.rejected),
+                    "n_preempted": self.n_preempted}
         lat = np.array([sr.latency for sr in done])
         per_server = np.bincount([sr.server for sr in done],
                                  minlength=len(self.specs)).tolist()
@@ -659,4 +710,4 @@ class PerLLMServer(Runtime, LinkStateMixin):
         m.set_gauge("admitted_success_rate",
                     stats["admitted_success_rate"])
         m.set_gauge("avg_processing_time", stats["avg_processing_time"])
-        return with_aliases(stats)
+        return stats

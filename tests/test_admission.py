@@ -391,7 +391,7 @@ def test_live_server_rejects_cleanly():
     done = srv.run_until_idle()
     assert done == []
     assert len(srv.rejected) == 1
-    assert srv.stats["rejected"] == 1
+    assert srv.stats["n_rejected"] == 1
     (out,) = policy.feedback_log
     assert out.rejected and out.energy == 0.0
 

@@ -215,7 +215,7 @@ def test_perllm_server_view_not_degenerate():
     assert list(view.uplink_free_at) == list(srv.uplink_free_at)
     assert any(f != 1.0 for f in view.bw_factor)
     srv.run_until_idle()
-    assert srv.stats["served"] == 4
+    assert srv.stats["n_served"] == 4
 
 
 def test_perllm_server_applies_defer_until():

@@ -21,9 +21,8 @@ import pytest
 from repro.cluster import Simulator, generate_workload, paper_testbed
 from repro.core import make_policy
 from repro.obs import (
-    DEPRECATED_ALIASES, KIND_ARM, KIND_DONE, KIND_INFER, KIND_QUEUE,
-    KIND_REJECT, KIND_TX, MetricsRegistry, TraceRecorder, with_aliases,
-    write_csv, write_perfetto,
+    KIND_ARM, KIND_DONE, KIND_INFER, KIND_QUEUE, KIND_REJECT, KIND_TX,
+    MetricsRegistry, TraceRecorder, write_csv, write_perfetto,
 )
 from repro.obs.export import validate_perfetto
 
@@ -270,21 +269,11 @@ def test_registry_as_dict_snapshot():
     assert snap["histograms"]["lat"][""]["counts"] == [1, 0]
 
 
-def test_deprecated_aliases_cover_old_names():
-    stats = with_aliases({"n_served": 5, "n_rejected": 1,
-                          "avg_processing_time": 0.5})
-    assert stats["served"] == 5
-    assert stats["rejected"] == 1
-    assert stats["mean_latency"] == 0.5
-    # canonical keys always win; aliases never overwrite
-    assert with_aliases({"n_served": 2, "served": 9})["served"] == 9
-
-
 def test_simresult_stats_canonical_and_aliased():
     res = _run("array", n=200)
     stats = res.stats()
-    for old, new in DEPRECATED_ALIASES.items():
-        if new in stats:
-            assert stats[old] == stats[new], (old, new)
     assert stats["n_served"] + stats["n_rejected"] == res.n_services
-    assert stats["served"] == stats["n_served"]
+    assert stats["n_served"] == sum(stats["per_server_served"])
+    assert stats["avg_processing_time"] == res.avg_processing_time
+    # canonical keys only: the pre-unification spellings are gone
+    assert not {"served", "rejected", "mean_latency"} & set(stats)

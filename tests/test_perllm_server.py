@@ -35,9 +35,9 @@ def test_server_serves_all_requests():
     assert len(done) == 10
     assert all(len(sr.engine_req.generated) == 3 for sr in done)
     stats = srv.stats
-    assert stats["served"] == 10
-    assert sum(stats["per_server"]) == 10
-    assert 0.0 <= stats["deadline_met"] <= 1.0
+    assert stats["n_served"] == 10
+    assert sum(stats["per_server_served"]) == 10
+    assert 0.0 <= stats["admitted_success_rate"] <= 1.0
 
 
 def test_server_learner_receives_outcomes():
@@ -80,16 +80,17 @@ def test_server_trace_spans_conserve_latency():
 
 
 def test_server_stats_canonical_keys_and_aliases():
-    from repro.obs import DEPRECATED_ALIASES
     srv = _server()
     for _ in range(5):
         srv.submit([1, 2, 3, 4], max_new_tokens=2, deadline=5.0)
     srv.run_until_idle()
     stats = srv.stats
     assert stats["n_served"] == 5
-    for old, new in DEPRECATED_ALIASES.items():
-        if new in stats:
-            assert stats[old] == stats[new], (old, new)
+    assert sum(stats["per_server_served"]) == 5
+    assert stats["n_prefills"] == sum(e.n_prefills for e in srv.engines)
+    # canonical keys only: the pre-unification spellings are gone
+    assert not {"served", "deadline_met", "per_server"} & set(stats)
     # engine-level stats share the same canonical namespace
     est = srv.engines[0].stats()
-    assert "n_prefills" in est and est["prefills"] == est["n_prefills"]
+    assert est["n_prefills"] == srv.engines[0].n_prefills
+    assert "prefills" not in est
