@@ -216,7 +216,8 @@ def phase_parity(cfg, *, prompt_len=32, seed=0):
                         paged=True, device=dev)
     rng = np.random.default_rng(seed)
     prompt = rng.integers(0, cfg.vocab_size, prompt_len).tolist()
-    prefill_logits, one_cache = eng._prefill(
+    # the prefill the engine's admission program runs, on its own
+    prefill_logits, one_cache = jax.jit(eng._prefill_cache)(
         eng.params, {"tokens": eng._put([prompt])}, eng._put(prompt_len - 1))
     first = int(jnp.argmax(prefill_logits[0]))
     decode_logits, _ = eng._decode(eng.params, eng._put([[first]]),

@@ -15,9 +15,17 @@ the batch. Eviction snapshots the slot's KV into the request's pages
 same engine reattaches its page table and resumes decoding with **zero
 re-prefill**; `release` drops a request's pages when the work moves
 elsewhere. The per-slot compute view stays the dense jitted cache (pages
-are scattered/gathered at evict/resume only), which keeps paged and dense
-decoding bit-identical; `repro.kernels.paged_attention` is the kernel
-that decodes straight from such a pool on TPU.
+are scattered/gathered at publish/evict/resume only), which keeps paged
+and dense decoding bit-identical; `repro.kernels.paged_attention` is the
+kernel that decodes straight from such a pool on TPU.
+
+A fresh prompt is admitted by one program per prompt bucket
+(`_admit_prompt`), with the batch cache and the page pools donated: it
+prefills into a one-slot cache, writes that into the slot's row in
+place, publishes the prompt's full blocks to the pool (when prefix
+sharing is live) and samples the first token; the host then reads one
+int. A prefix hit's suffix and a resume write their slot through the
+same donated slot write (`_write_slot`).
 
 Each engine lives on one device (`device`, default the first): its params,
 dense cache and page pool are committed there, so the jitted steps run
@@ -25,9 +33,11 @@ there and a fleet of engines can spread over a host's chips.
 
 With a `repro.obs.TraceRecorder` attached (`attach_trace`), each step
 records measured-clock spans: `engine.run` around the step, one
-`engine.admission` per request admitted (with `engine.prefill`,
-`engine.first_token`, `engine.kv_store`, `engine.kv_load` and
-`engine.kv_insert` inside), then `engine.decode` (the decode and sampling
+`engine.admission` per request admitted (with `engine.prefill`, the
+dispatch of the admission program or a prefix hit's suffix, and
+`engine.first_token`, the host waiting for the first token; a prefix hit
+adds `engine.kv_store` and `engine.kv_insert`, a resume `engine.kv_load`
+and `engine.kv_insert`), then `engine.decode` (the decode and sampling
 dispatches), `engine.token_wait` (the host waiting for the sampled
 tokens; its end is every token's host time) and `engine.emit`.
 """
@@ -92,6 +102,8 @@ class ServingEngine:
     # token shape, prefill at one per pow-2 seq bucket
     decode_compiles = counter_attr("engine.decode_compiles")
     prefill_compiles = counter_attr("engine.prefill_compiles")
+    # admissions served by the one-program admission (`_admit_prompt`)
+    fused_admissions = counter_attr("engine.fused_admissions")
 
     def __init__(self, cfg: ModelConfig, params, max_batch: int = 8,
                  max_seq: int = 1024, ctx: Optional[ParallelContext] = None,
@@ -107,7 +119,10 @@ class ServingEngine:
         self.max_seq = max_seq
         self.temperature = temperature
         with jax.default_device(self.device):
-            self.cache = M.init_cache(cfg, max_batch, max_seq)
+            # committed, as the admission program's outputs are: one
+            # compile per shape, whichever cache it is handed
+            self.cache = jax.device_put(M.init_cache(cfg, max_batch, max_seq),
+                                        self.device)
         self._axis = _batch_axis_tree(cfg, max_seq)
         self.positions = np.zeros(max_batch, np.int32)
         self.cur_tokens = np.zeros(max_batch, np.int32)
@@ -159,7 +174,28 @@ class ServingEngine:
                                              ctx=self.ctx, mode="prefill",
                                              cache=c)
             return logits[:, last], new_cache
-        self._prefill = jax.jit(_prefill_cache)
+        self._prefill_cache = _prefill_cache
+
+        # a one-slot cache into row `slot` of the donated batch cache:
+        # XLA writes the row in place instead of copying the whole cache
+        def write_slot(cache, one, slot):
+            return jax.tree.map(
+                lambda c, o, ax: jax.lax.dynamic_update_slice_in_dim(
+                    c, o, slot, ax), cache, one, self._axis)
+        self._write_slot = jax.jit(write_slot, donate_argnums=0)
+
+        # a fresh prompt's whole admission in one dispatch: prefill, slot
+        # write and, given the pool leaves (an empty list when no pages
+        # are published), the prompt's full blocks to pages `ids`; then
+        # the first token. Cache and pools are donated.
+        def admit_prompt(p, cache, pools, batch, last, slot, ids, key):
+            logits, one = _prefill_cache(p, batch, last)
+            cache = write_slot(cache, one, slot)
+            if pools:          # repro-check: disable=R7 (a static list)
+                pools = self.kv.publish(pools, one, ids)
+            tok = sample_tokens(key, logits, self.temperature)[0]
+            return tok, cache, pools
+        self._admit_prompt = jax.jit(admit_prompt, donate_argnums=(1, 2))
 
     # ------------------------------------------------------------------
     def attach_trace(self, trace, server: int = -1) -> None:
@@ -243,12 +279,11 @@ class ServingEngine:
         return jax.device_put(np.array(host_value, np.int32), self.device)
 
     def _insert_slot(self, slot: int, single_cache, sid: int) -> None:
-        def ins(pool, one, ax):
-            return jax.lax.dynamic_update_slice_in_dim(pool, one, slot, ax)
         tr = self.trace
         sp = tr.begin("engine.kv_insert", sid, self._trace_server) \
             if tr is not None else None
-        self.cache = jax.tree.map(ins, self.cache, single_cache, self._axis)
+        self.cache = self._write_slot(self.cache, single_cache,
+                                      self._put(slot))
         if sp is not None:
             tr.end(sp)
 
@@ -291,18 +326,17 @@ class ServingEngine:
                     continue
             self.queue.pop(0)
             plen = len(req.prompt)
-            skip = 0
-            if self.paged and req.pages is not None \
-                    and req.pages.shared_blocks > 0:
-                skip = req.pages.shared_blocks * self.kv.block_tokens
             if adm is not None:
                 sp = tr.begin("engine.prefill", req.sid, self._trace_server)
-            if skip > 0:
+            self._key, k = jax.random.split(self._key)
+            if self.paged and req.pages is not None \
+                    and req.pages.shared_blocks > 0:
                 # prefix hit: the table's read-shared head already holds
                 # the prompt's first `skip` tokens of KV — gather the
                 # pages and prefill only the suffix, one token at a time
                 # through the decode step (its shape-polymorphic jit
                 # serves batch 1; `allocate` guarantees skip < plen)
+                skip = req.pages.shared_blocks * self.kv.block_tokens
                 one_cache = self.kv.load(req.pages, [])
                 logits = None
                 self._note_compile("decode", (1, 1))
@@ -310,9 +344,20 @@ class ServingEngine:
                     logits, one_cache = self._decode(
                         self.params, self._put([[req.prompt[i]]]),
                         one_cache, self._put([i]))
-                last_logits = logits
                 self.n_prefix_hits += 1
                 self.prefix_tokens_reused += skip
+                # publish the suffix's full blocks while they really hold
+                # its KV (pages are otherwise only written at eviction)
+                if adm is not None:
+                    sp = tr.then(sp, "engine.kv_store")
+                self.kv.store_prefix(req.pages, one_cache, n_tokens=plen)
+                self.kv.register_prefix(req.prompt, req.pages)
+                if adm is not None:
+                    sp = tr.then(sp, "engine.first_token")
+                tok = int(sample_tokens(k, logits, self.temperature)[0])
+                if adm is not None:
+                    tr.end(sp)
+                self._insert_slot(slot, one_cache, req.sid)
             else:
                 bucket = 1 << max(plen - 1, 1).bit_length()  # next pow2 >= plen
                 bucket = min(bucket, self.max_seq)
@@ -323,28 +368,31 @@ class ServingEngine:
                     s = prompt.shape[1]
                     batch["positions"] = self._put(np.broadcast_to(
                         np.arange(s, dtype=np.int32), (3, 1, s)))
+                # with prefix sharing live the program also publishes the
+                # prompt's full blocks, so later admissions can attach
+                # them copy-on-write; the ids are padded to the bucket
+                pools, ids = [], None
+                if self._sharing:
+                    pools = self.kv.pools
+                    ids = self._put(self.kv.publish_ids(
+                        req.pages, plen, bucket // self.kv.block_tokens))
                 # deliberate shape polymorphism: the pow-2 bucketing above
                 # caps this at log2(max_seq) distinct prefill shapes, and
                 # `engine.prefill_compiles` counts them at runtime
                 self._note_compile("prefill", prompt.shape)
-                last_logits, one_cache = self._prefill(  # repro-check: disable=R8
-                    self.params, batch, self._put(plen - 1))
-            self.n_prefills += 1
-            if self._sharing:
-                # publish the prompt's full blocks while they really hold
-                # its KV (pages are otherwise only written at eviction) so
-                # later admissions can attach them copy-on-write
+                tok, self.cache, pools = self._admit_prompt(  # repro-check: disable=R8
+                    self.params, self.cache, pools, batch,
+                    self._put(plen - 1), self._put(slot), ids, k)
+                self.metrics.inc("engine.fused_admissions")
+                if self._sharing:
+                    self.kv.pools = pools
+                    self.kv.register_prefix(req.prompt, req.pages)
                 if adm is not None:
-                    sp = tr.then(sp, "engine.kv_store")
-                self.kv.store_prefix(req.pages, one_cache, n_tokens=plen)
-                self.kv.register_prefix(req.prompt, req.pages)
-            if adm is not None:
-                sp = tr.then(sp, "engine.first_token")
-            self._key, k = jax.random.split(self._key)
-            tok = int(sample_tokens(k, last_logits, self.temperature)[0])
-            if adm is not None:
-                tr.end(sp)
-            self._insert_slot(slot, one_cache, req.sid)
+                    sp = tr.then(sp, "engine.first_token")
+                tok = int(tok)
+                if adm is not None:
+                    tr.end(sp)
+            self.n_prefills += 1
             req.slot = slot
             req.generated.append(tok)
             req.first_token_at = time.time()

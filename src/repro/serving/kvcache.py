@@ -28,10 +28,12 @@ makes KV memory a real, countable resource instead:
   scatter a slot's dense per-request cache into that request's pages
   (`store`, at eviction) and gather it back into a dense slot cache
   (`load`, at resume) — which is what lets a preempted request re-enter
-  *without re-running prefill*. Leaves with no sequence axis (SSM/conv
-  states, rolling windows smaller than `max_seq`) are snapshotted wholesale
-  in the returned state list; they are per-request O(1)-sized state, not
-  paged memory.
+  *without re-running prefill*. `publish` writes a prefilled prompt's
+  full blocks as a pure function of the pools, so the engine runs it
+  inside its admission program with the pools donated. Leaves with no
+  sequence axis (SSM/conv states, rolling windows smaller than `max_seq`)
+  are snapshotted wholesale in the returned state list; they are
+  per-request O(1)-sized state, not paged memory.
 
 Layout note: pool leaves keep each cache leaf's own layout with the
 sequence axis split as `(block, block_tokens)` and moved to the front, so
@@ -49,6 +51,7 @@ from typing import Any, List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as M
@@ -311,6 +314,7 @@ class PagedKVCache:
         self.prefix: Optional[PrefixIndex] = \
             PrefixIndex(self.allocator, block_tokens) \
             if self.supports_prefix else None
+        self._publish = jax.jit(self.publish, donate_argnums=0)
 
     # ------------------------------------------------------------------
     @property
@@ -421,31 +425,73 @@ class PagedKVCache:
             self._pools[i] = self._pools[i].at[ids].set(pages)
         return state
 
+    @property
+    def pools(self) -> List[Optional[Any]]:
+        """The pool leaves in the cache tree's flat order (None for a
+        leaf with no sequence axis)."""
+        return self._pools
+
+    @pools.setter
+    def pools(self, pools: List[Optional[Any]]) -> None:
+        self._pools = list(pools)
+
+    def publish_ids(self, table: PageTable, n_tokens: int,
+                    n_pages: int) -> np.ndarray:
+        """`n_pages` page ids for `publish`: entry k is `table`'s block k
+        for each full block of the first `n_tokens` past the read-shared
+        head, and `n_blocks` (out of range, so dropped) elsewhere."""
+        n_full = min(len(table.blocks), n_tokens // self.block_tokens,
+                     n_pages)
+        skip = table.shared_blocks
+        ids = np.full(n_pages, self.n_blocks, np.int32)
+        ids[skip:n_full] = table.blocks[skip:n_full]
+        return ids
+
+    def publish(self, pools: List[Optional[Any]], slot_cache, ids):
+        """`pools` with block k of the dense single-slot `slot_cache` (its
+        tokens `[k * block_tokens, (k + 1) * block_tokens)`) written to
+        page `ids[k]`, for every k < len(ids); an out-of-range id drops
+        its block. Pure, so it traces into a program that donates
+        `pools`; the shapes depend only on len(ids). Each page is its own
+        in-place slice update: a scatter would have XLA relayout (copy)
+        the whole pool around it on TPU."""
+        bt = self.block_tokens
+        out = []
+        for pool, leaf, axis in zip(pools, self._flatten(slot_cache),
+                                    self._seq_axis, strict=True):
+            if axis is None or ids.shape[0] == 0:
+                out.append(pool)
+                continue
+
+            def write(k, pool, leaf=leaf, axis=axis):
+                block = jax.lax.dynamic_slice_in_dim(leaf, k * bt, bt, axis)
+                page = jnp.moveaxis(block, axis, 0)[None]
+                return jax.lax.cond(
+                    ids[k] < pool.shape[0],
+                    lambda p: jax.lax.dynamic_update_slice_in_dim(
+                        p, page, ids[k], 0),
+                    lambda p: p, pool)
+            out.append(jax.lax.fori_loop(0, ids.shape[0], write, pool))
+        return out
+
     def store_prefix(self, table: PageTable, slot_cache,
                      n_tokens: int) -> None:
         """Persist a live slot's *full* blocks (the first
         `n_tokens // block_tokens` pages, minus the read-shared head)
-        into the pool — called right after prefill so `register_prefix`
-        publishes pages that actually hold the prompt's KV (ordinarily
-        pages are only written at eviction)."""
-        bt = self.block_tokens
-        n_full = min(len(table.blocks), n_tokens // bt)
-        skip = table.shared_blocks
-        if n_full <= skip:
-            return
-        ids = jnp.asarray(table.blocks[skip:n_full], jnp.int32)
-        for i, leaf in enumerate(self._flatten(slot_cache)):
-            axis = self._seq_axis[i]
-            if axis is None:
-                continue
-            lead = jnp.moveaxis(leaf, axis, 0)[skip * bt:n_full * bt]
-            pages = lead.reshape((n_full - skip, bt) + lead.shape[1:])
-            self._pools[i] = self._pools[i].at[ids].set(pages)
+        into the pool, through `publish` with the pools donated — so
+        `register_prefix` publishes pages that actually hold the prompt's
+        KV (ordinarily pages are only written at eviction)."""
+        ids = self.publish_ids(table, n_tokens,
+                               self.max_seq // self.block_tokens)
+        if (ids < self.n_blocks).any():
+            self._pools = self._publish(
+                self._pools, slot_cache, jax.device_put(ids, self.device))
 
     def register_prefix(self, prompt: List[int],
                         table: PageTable) -> None:
         """Publish a prefilled prompt's full blocks to the prefix index
-        (no-op without one). Call after `store_prefix`."""
+        (no-op without one). Call once its pages are written
+        (`store_prefix`, or `publish` in the engine's admission)."""
         if self.prefix is not None:
             self.prefix.register(prompt, table.blocks)
 

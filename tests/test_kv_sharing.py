@@ -6,7 +6,8 @@ COW forking, release, export/import migration, and index reclaim conserves
 the block pool — no block leaks, no block is double-freed, and every
 block's refcount equals its actual holder count; nominal non-shared runs
 stay bit-exact with sharing enabled; an engine prefix hit skips the shared
-prefill yet generates bit-identically to a cold engine; in the simulator
+prefill yet generates bit-identically to a cold engine, reading back the
+KV the admission program published; in the simulator
 shared-prefix workloads bank measurable prefill savings, a cross-server
 requeue with `Decision.migrate_kv` resumes with zero re-prefill while its
 transfer occupies the per-link bandwidth ledgers, and a refused migration
@@ -400,3 +401,43 @@ def test_shared_prefix_scenario_shapes_pools():
     # arrivals stay the baseline Poisson process (request-for-request
     # comparable against the unshared workload)
     assert [r.arrival for r in shaped] == [r.arrival for r in base]
+
+
+@pytest.mark.parametrize("plen", [16, 17, 31, 33])
+def test_prefix_published_by_admission_serves_a_hit(engine_setup, plen):
+    """The pages one admission program publishes serve a later request
+    that opens with the prompt's full blocks: it hits the index, its slot
+    reads back exactly the KV the first prompt's prefill wrote there, and
+    it generates what a sharing-disabled engine does."""
+    import numpy as np
+    jax = pytest.importorskip("jax")
+    rng = np.random.default_rng(plen)
+    first = rng.integers(1, 512, plen).tolist()
+    head = 16 * (plen // 16)
+    second = first[:head] + rng.integers(1, 512, 3).tolist()
+    cold = _engine(engine_setup, paged=True, kv_block_tokens=16,
+                   prefix_sharing=False)
+    warm = _engine(engine_setup, paged=True, kv_block_tokens=16)
+
+    def head_kv(eng, req):
+        one = eng._extract_slot(req.slot)
+        return [np.moveaxis(np.asarray(leaf, np.float32), ax, 0)[:head]
+                for leaf, ax in zip(jax.tree.leaves(one), eng.kv._seq_axis)]
+
+    want = cold.submit(list(first), max_new_tokens=6)
+    cold._admit()
+    kv_first = head_kv(cold, want)
+    cold.run_until_idle()
+    cold.submit(list(second), max_new_tokens=6)
+    cold.run_until_idle()
+    warm.submit(list(first), max_new_tokens=6)
+    warm.run_until_idle()
+    hit = warm.submit(list(second), max_new_tokens=6)
+    warm._admit()
+    assert warm.n_prefix_hits == 1 and warm.prefix_tokens_reused == head
+    for got, exp in zip(head_kv(warm, hit), kv_first, strict=True):
+        np.testing.assert_array_equal(got, exp)
+    warm.run_until_idle()
+    assert [r.generated for r in warm.completed] \
+        == [r.generated for r in cold.completed]
+    assert warm.fused_admissions == 1 and warm.n_prefills == 2

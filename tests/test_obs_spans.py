@@ -176,8 +176,24 @@ def test_engine_spans_and_rid_fallback():
     assert sorted(set(sp["sid"][adm])) == sorted({reqs[0].rid, reqs[1].rid,
                                                  77})
     assert reqs[2].sid == 77 and reqs[0].sid == reqs[0].rid
-    # decode-step children hang off engine.run
+    # a fresh prompt's admission is one program: its dispatch and the wait
+    # for its first token; only prefix hits (their suffix's page write and
+    # slot write) and resumes (gather and slot write) dispatch KV ops
     rows = _rows(sp)
+    kids = {}
+    for s, (n, p, *_r) in rows.items():
+        kids.setdefault(p, []).append(n)
+    admissions = [kids.get(s, []) for s, (n, *_r) in rows.items()
+                  if n == "engine.admission"]
+    fresh = [k for k in admissions if "engine.kv_store" not in k
+             and "engine.kv_load" not in k]
+    assert eng.fused_admissions == len(fresh) == 1
+    assert all(k == ["engine.prefill", "engine.first_token"] for k in fresh)
+    names_all = list(sp["name"])
+    assert names_all.count("engine.kv_store") == eng.n_prefix_hits == 2
+    assert names_all.count("engine.kv_load") == 1
+    assert names_all.count("engine.kv_insert") == eng.n_prefix_hits + 1
+    # decode-step children hang off engine.run
     run_ids = {s for s, (n, *_r) in rows.items() if n == "engine.run"}
     for s, (n, p, *_r) in rows.items():
         if n in ("engine.decode", "engine.token_wait", "engine.emit",

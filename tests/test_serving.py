@@ -1,6 +1,8 @@
 """Serving engine: continuous batching, slot reuse, per-slot positions."""
 import jax
 import jax.numpy as jnp
+import numpy as np
+import pytest
 
 from repro.configs import get_config
 from repro.models import cpu_context, decode_step, init_cache, init_params, prefill
@@ -102,7 +104,7 @@ def test_engine_compile_counters():
     # the counters mirror jit's own shape-keyed cache when it exposes one
     if hasattr(eng._decode, "_cache_size"):
         assert eng._decode._cache_size() == eng.decode_compiles
-        assert eng._prefill._cache_size() == eng.prefill_compiles
+        assert eng._admit_prompt._cache_size() == eng.prefill_compiles
 
 
 def test_prefix_hit_decode_compile_counted_once():
@@ -122,3 +124,150 @@ def test_prefix_hit_decode_compile_counted_once():
     eng.run_until_idle()
     assert eng.n_prefix_hits == 2
     assert eng.decode_compiles == d0 + 1
+
+
+# ---------------------------------------------------------------------------
+# one-program admission: the same results as the eager sequence it replaced
+# ---------------------------------------------------------------------------
+
+
+def _eager_admission(eng, prompt, slot, key, cache, pools, table):
+    """The admission as a sequence of separate ops, as the engine ran it
+    before it became one program: the prefill program, the first token
+    sampled eagerly, then `store_prefix`'s per-leaf scatters of the full
+    blocks past the read-shared head and `_insert_slot`'s per-leaf slot
+    update. Returns (token, batch cache, pools)."""
+    plen = len(prompt)
+    bucket = min(1 << max(plen - 1, 1).bit_length(), eng.max_seq)
+    tokens = jnp.asarray([prompt + [0] * (bucket - plen)], jnp.int32)
+    batch = {"tokens": tokens}
+    if eng.cfg.mrope:
+        batch["positions"] = jnp.broadcast_to(
+            jnp.arange(bucket, dtype=jnp.int32), (3, 1, bucket))
+    logits, one = jax.jit(eng._prefill_cache)(eng.params, batch,
+                                              jnp.int32(plen - 1))
+    _, k = jax.random.split(key)
+    tok = int(sample_tokens(k, logits, eng.temperature)[0])
+    pools = list(pools)
+    if table is not None:
+        bt = eng.kv.block_tokens
+        n_full = min(len(table.blocks), plen // bt)
+        skip = table.shared_blocks
+        ids = jnp.asarray(table.blocks[skip:n_full], jnp.int32)
+        for i, leaf in enumerate(jax.tree.leaves(one)):
+            if eng.kv._seq_axis[i] is None or n_full <= skip:
+                continue
+            lead = jnp.moveaxis(leaf, eng.kv._seq_axis[i], 0)
+            lead = lead[skip * bt:n_full * bt]
+            pools[i] = jnp.asarray(pools[i]).at[ids].set(
+                lead.reshape((n_full - skip, bt) + lead.shape[1:]))
+    cache = jax.tree.map(
+        lambda c, o, ax: jax.lax.dynamic_update_slice_in_dim(c, o, slot, ax),
+        cache, one, eng._axis)
+    return tok, cache, pools
+
+
+def _assert_same(got, want):
+    assert len(jax.tree.leaves(got)) == len(jax.tree.leaves(want))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
+def _admit_checked(eng, prompt, max_new_tokens=4):
+    """Admit `prompt` (a fresh prompt: no prefix hit) through the engine's
+    one-program admission and check the first token, the whole batch
+    cache and every page pool against `_eager_admission`."""
+    slot = eng.slot_req.index(None)
+    key, hits, fused = eng._key, eng.n_prefix_hits, eng.fused_admissions
+    # copies: the engine donates these buffers to the program
+    cache = jax.tree.map(np.array, eng.cache)
+    pools = [None if p is None else np.array(p)
+             for p in eng.kv.pools] if eng.paged else []
+    req = eng.submit(prompt, max_new_tokens=max_new_tokens)
+    eng._admit()
+    assert req.slot == slot and eng.n_prefix_hits == hits
+    assert eng.fused_admissions == fused + 1
+    tok, want_cache, want_pools = _eager_admission(
+        eng, prompt, slot, key, cache, pools,
+        req.pages if eng._sharing else None)
+    assert req.generated[0] == tok
+    _assert_same(eng.cache, want_cache)
+    if eng.paged:
+        _assert_same(eng.kv.pools, want_pools)
+    return req
+
+
+_MODES = {"dense": dict(), "paged": dict(paged=True),
+          "paged-unshared": dict(paged=True, prefix_sharing=False),
+          "paged-sampled": dict(paged=True, temperature=1.0, seed=3)}
+
+
+@pytest.fixture(scope="module", params=list(_MODES))
+def resident_engine(request):
+    """An engine of each mode with a long request resident in slot 0, so
+    the admissions into slot 1 must leave its row alone."""
+    eng = ServingEngine(CFG, _params(), max_batch=2, max_seq=64,
+                        **_MODES[request.param])
+    eng.submit(list(range(400, 420)), max_new_tokens=40)
+    eng.step()
+    return eng
+
+
+@pytest.mark.parametrize("plen", [1, 15, 16, 17, 31, 33])
+def test_fused_admission_matches_eager_sequence(resident_engine, plen):
+    """Token, batch cache (the new slot's row written, the resident's left
+    alone) and published pages equal the eager sequence's, for prompts
+    around the 16-token block and the pow-2 buckets."""
+    eng = resident_engine
+    prompt = np.random.default_rng(plen).integers(
+        1, CFG.vocab_size, plen).tolist()
+    req = _admit_checked(eng, prompt)
+    if eng._sharing:
+        # the index now serves the full blocks from the table's pages
+        assert eng.kv.match_prefix(prompt + [0]) \
+            == req.pages.blocks[:plen // 16]
+    eng.evict(req.slot, keep_kv=False)
+
+
+def test_fused_admission_compiles_once_per_bucket():
+    """Fresh prompts of 1-33 tokens compile one admission program per pow-2
+    bucket (2, 16, 32, 64), and every one of them is a fused admission;
+    a prefix hit afterwards is a prefill but not a fused admission."""
+    eng = ServingEngine(CFG, _params(), max_batch=2, max_seq=64, paged=True)
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, CFG.vocab_size, n).tolist()
+               for n in (1, 15, 16, 17, 31, 33)]
+    for p in prompts:
+        eng.submit(p, max_new_tokens=3)
+    eng.run_until_idle()
+    assert eng.prefill_compiles == 4
+    assert eng._admit_prompt._cache_size() == 4
+    assert eng.fused_admissions == eng.n_prefills == 6
+    assert eng.n_prefix_hits == 0
+    eng.submit(prompts[-1][:32] + [5, 6], max_new_tokens=3)
+    eng.run_until_idle()
+    assert eng.n_prefix_hits == 1 and eng.n_prefills == 7
+    assert eng.fused_admissions == 6 and eng.prefill_compiles == 4
+
+
+# one reduced config per cache family: dense MQA, GQA, MLA, MoE (with a
+# leading dense layer), mrope, rolling window, SSM, hybrid recurrent
+_FAMILIES = ["gemma-2b", "yi-6b", "minicpm3-4b", "mixtral-8x7b",
+             "deepseek-moe-16b", "qwen2-vl-2b", "gemma3-12b", "mamba2-2.7b",
+             "recurrentgemma-2b"]
+
+
+@pytest.mark.parametrize("arch", _FAMILIES)
+def test_fused_admission_each_cache_family(arch):
+    """Every cache family admits through the one program (the pools it
+    publishes to decided by the probed sequence axes) and decodes on."""
+    cfg = get_config(arch).reduced()
+    eng = ServingEngine(cfg, init_params(jax.random.key(0), cfg),
+                        max_batch=2, max_seq=128, paged=True)
+    prompt = np.random.default_rng(1).integers(1, cfg.vocab_size,
+                                               20).tolist()
+    req = _admit_checked(eng, prompt)
+    eng.run_until_idle()
+    assert len(req.generated) == 4
+    assert eng.fused_admissions == eng.n_prefills == 1

@@ -110,3 +110,42 @@ def test_yi6b_decode_step_fits_one_v5e(one_chip):
     # 2 layers of weights plus both 64000-token embedding tables
     assert mem.argument_size_in_bytes > 2 * 64000 * 4096 * 2
     assert need < V5E_HBM_BYTES
+
+
+def test_yi6b_admission_program_writes_cache_and_pools_in_place(one_chip):
+    """The engine's one-program admission at Yi-6B's published widths, cut
+    to 2 layers, for the benchmark's 8 lanes, 2,144-token lanes and
+    1,072-page pool: every byte of the donated batch cache and page pools
+    is aliased to the program's outputs (written in place, no copy of
+    either), and its scratch is no more than the prefill program's own
+    scratch and one-slot cache."""
+    from repro.serving import ServingEngine
+    cfg = dataclasses.replace(YI, n_layers=2)
+    # no weights needed to compile: only the cache and pools are built
+    eng = ServingEngine(cfg, None, max_batch=8, max_seq=2144, paged=True,
+                        kv_blocks=1072)
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+
+    def nbytes(tree):
+        return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+    cache = placed(jax.eval_shape(lambda: eng.cache))
+    pools = placed(jax.eval_shape(lambda: eng.kv.pools))
+    params = placed(M.params_shapes(cfg))
+    i32 = jnp.int32
+    bucket = 512
+    batch = {"tokens": _sds((1, bucket), i32, one_chip)}
+    last = _sds((), i32, one_chip)
+    mem = eng._admit_prompt.lower(
+        params, cache, pools, batch, last, _sds((), i32, one_chip),
+        _sds((bucket // 16,), i32, one_chip),
+        _sds((), jax.random.key(0).dtype, one_chip)).compile(
+        ).memory_analysis()
+    alone = jax.jit(eng._prefill_cache).lower(
+        params, batch, last).compile().memory_analysis()
+    assert mem.alias_size_in_bytes == nbytes(cache) + nbytes(pools)
+    assert mem.temp_size_in_bytes <= alone.temp_size_in_bytes \
+        + alone.output_size_in_bytes
