@@ -2,6 +2,10 @@
 
 Edge: Yi-6B, LLaMA2-7B, LLaMA3-8B, Yi-9B. Cloud: LLaMA2-33B.
 These drive the edge-cloud cluster cost model in `repro.cluster`.
+
+Yi-34B is the 33B-class cloud model of the edge models' own family, the
+stand-in for LLaMA2-33B where a cloud replica is served: 56 query heads
+over 8 KV heads (a GQA group of 7) at d 7,168.
 """
 from repro.configs.base import ModelConfig, register
 
@@ -33,6 +37,13 @@ LLAMA2_33B = register(ModelConfig(
     arch_id="llama2-33b", family="dense", citation="arXiv:2307.09288",
     n_layers=60, d_model=6656, n_heads=52, n_kv_heads=52, head_dim=128,
     d_ff=17920, vocab_size=32000, activation="swiglu",
+))
+
+YI_34B = register(ModelConfig(
+    arch_id="yi-34b", family="dense", citation="hf:01-ai/Yi-34B",
+    n_layers=60, d_model=7168, n_heads=56, n_kv_heads=8, head_dim=128,
+    d_ff=20480, vocab_size=64000, activation="swiglu", norm_eps=1e-5,
+    rope_theta=5_000_000.0,
 ))
 
 EDGE_MODELS = ("yi-6b", "llama2-7b", "llama3-8b", "yi-9b")

@@ -104,6 +104,12 @@ class ServingEngine:
     prefill_compiles = counter_attr("engine.prefill_compiles")
     # admissions served by the one-program admission (`_admit_prompt`)
     fused_admissions = counter_attr("engine.fused_admissions")
+    # measured host time (`time.time_ns`) from the end of one `step` to
+    # the start of the next, summed over the steps that began with lanes
+    # occupied, and how many: on a fleet stepped from one loop, the time
+    # this replica's requests waited while the loop stepped its peers
+    step_gap_ns = counter_attr("engine.step_gap_ns")
+    step_gaps = counter_attr("engine.step_gaps")
 
     def __init__(self, cfg: ModelConfig, params, max_batch: int = 8,
                  max_seq: int = 1024, ctx: Optional[ParallelContext] = None,
@@ -149,6 +155,7 @@ class ServingEngine:
         # site is guarded by `if self.trace is not None`
         self.trace = None
         self._trace_server = -1
+        self._step_end_ns = -1     # end of the last `step`; -1 before one
         self.kv: Optional[PagedKVCache] = None
         if paged:
             # default pool: the dense reservation's worth of blocks
@@ -472,6 +479,16 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def step(self) -> int:
         """Admit + one decode step for all active slots. Returns #active."""
+        t0 = time.time_ns()
+        if self._step_end_ns >= 0 and self.active_slots:
+            self.step_gap_ns += t0 - self._step_end_ns
+            self.step_gaps += 1
+        try:
+            return self._step()
+        finally:
+            self._step_end_ns = time.time_ns()
+
+    def _step(self) -> int:
         tr = self.trace
         if tr is not None:
             run = tr.begin("engine.run", -1, self._trace_server)

@@ -124,6 +124,7 @@ def test_param_counts_match_published():
         "qwen2-vl-2b": 1.5e9, "gemma3-12b": 11.8e9,
         "recurrentgemma-2b": 2.7e9, "gemma-2b": 2.5e9,
         "whisper-base": 0.08e9, "gemma3-27b": 27.0e9,
+        "yi-34b": 34.4e9,
     }
     for arch, want in expected.items():
         got = get_config(arch).param_count()
@@ -131,7 +132,7 @@ def test_param_counts_match_published():
 
 
 @pytest.mark.parametrize("arch", ["yi-6b", "llama2-7b", "llama3-8b",
-                                  "yi-9b", "llama2-33b"])
+                                  "yi-9b", "llama2-33b", "yi-34b"])
 def test_paper_deployment_models_forward(arch, key):
     """The paper's own edge/cloud models also instantiate and run."""
     cfg = get_config(arch).reduced()

@@ -22,6 +22,7 @@ import numpy as np
 
 from repro.cluster import paper_testbed
 from repro.configs import get_config
+from repro.core import make_policy
 from repro.models import init_params
 from repro.obs import TraceRecorder
 from repro.obs import trace as trace_mod
@@ -136,12 +137,13 @@ def _engine(**kw):
                          max_batch=2, max_seq=64, **kw)
 
 
-def _server():
+def _server(policy=None):
     key = jax.random.key(0)
     specs = paper_testbed(n_edge=2)[:2]
     engines = [ServingEngine(_CFG, init_params(key, _CFG), max_batch=2,
                              max_seq=64, paged=True) for _ in specs]
-    return PerLLMServer(specs, engines)
+    return PerLLMServer(specs, engines, scheduler=make_policy(
+        policy, len(specs)) if policy else None)
 
 
 def _serve(srv, n=6):
@@ -257,6 +259,50 @@ def test_off_path_tokens_identical_and_nothing_recorded():
     n = rec.n_spans
     _serve(traced, n=2)
     assert rec.n_spans == n
+
+
+# the perllm policy sends this burst to one edge; this baseline spreads it
+# over both, so both replicas tick
+SPREAD = "rewardless-guidance"
+
+
+def test_fleet_engine_runs_carry_their_replica_and_change_no_token():
+    plain, traced = _server(SPREAD), _server(SPREAD)
+    rec = TraceRecorder()
+    traced.attach_trace(rec)
+    a = _serve(plain, n=10)
+    b = _serve(traced, n=10)
+    assert [sr.engine_req.generated for sr in a] == \
+        [sr.engine_req.generated for sr in b]
+    assert [sr.server for sr in a] == [sr.server for sr in b]
+    rows = _rows(rec.spans())
+    runs = [(p, srv) for n, p, _sid, srv in rows.values()
+            if n == "engine.run"]
+    # each engine tick of the event loop is one top-level engine.run,
+    # labeled with the replica the loop was stepping
+    assert runs and {p for p, _ in runs} == {-1}
+    assert {srv for _, srv in runs} == {sr.server for sr in b} == {0, 1}
+
+
+def test_engine_step_gaps_lie_between_its_runs():
+    srv = _server(SPREAD)
+    rec = TraceRecorder()
+    srv.attach_trace(rec)
+    _serve(srv, n=10)
+    sp = rec.spans()
+    names, servers = sp["name"], sp["server"]
+    t0, t1 = sp["t0_ns"], sp["t1_ns"]
+    for j, eng in enumerate(srv.engines):
+        mine = (names == "engine.run") & (servers == j)
+        runs = int(mine.sum())
+        # a gap is counted before a step that found lanes occupied, so not
+        # before the first; each lies between two of the engine's
+        # engine.run spans, and is measured just outside them
+        assert 1 <= eng.step_gaps <= runs - 1
+        between = t0[mine][1:] - t1[mine][:-1]
+        assert (between >= 0).all()
+        assert 0 < eng.step_gap_ns <= between.sum()
+        assert eng.metrics.get_scalar("engine.step_gaps") == eng.step_gaps
 
 
 # ---------------------------------------------------------------------------

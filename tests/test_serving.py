@@ -271,3 +271,110 @@ def test_fused_admission_each_cache_family(arch):
     eng.run_until_idle()
     assert len(req.generated) == 4
     assert eng.fused_admissions == eng.n_prefills == 1
+
+
+# ---------------------------------------------------------------------------
+# parity with a plain float32 reference at a GQA group of 7 (Yi-34B's 56
+# query heads over 8 KV heads) and of 8
+# ---------------------------------------------------------------------------
+
+def _reference_modules():
+    """The benchmark's plain float32 reference (`references/llama.py`), its
+    seeded weights and the adapter that hands them to the program."""
+    import importlib.util
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmarks",
+                         "chip")
+    sys.path.insert(0, os.path.abspath(bench))
+    from chipbench import check, fleet, model, weights
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_reference_llama",
+        os.path.join(bench, "references", "llama.py"))
+    ref = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(ref)
+    return ref, check, fleet, model, weights
+
+
+# (query heads, KV heads, dtype, tolerance on |logit - reference logit|).
+# float32: the program and the reference differ only in summation order
+# and the masking constant; they read 5e-6 on logits of order 1.
+# bfloat16 (the served dtype): every activation is rounded to 8 bits of
+# mantissa; they read 0.04 after two layers, while the reference's float8
+# control (one precision below) misses the same logits by 0.6.
+_GQA = {"group7-f32": (14, 2, "float32", 2e-4),
+        "group8-f32": (16, 2, "float32", 2e-4),
+        "group7-bf16": (14, 2, "bfloat16", 0.15),
+        "group8-bf16": (16, 2, "bfloat16", 0.15)}
+
+
+@pytest.mark.parametrize("case", list(_GQA))
+def test_paged_engine_matches_float32_reference_at_gqa_group(case):
+    """A Yi-shaped model at test widths (heads of 16, 2 KV heads; GQA group
+    7 as Yi-34B's, or 8) on a paged engine: the first token's logits from
+    the engine's prefill, then every decode step's logits with the lanes at
+    different positions, against the plain float32 reference's full
+    forward over the prompt and the served tokens."""
+    ref, check, fleet, model, weights = _reference_modules()
+    n_heads, n_kv, dtype, tol = _GQA[case]
+    m = model.Shape.from_config("gqa", {
+        "hidden_size": 16 * n_heads, "intermediate_size": 512,
+        "num_attention_heads": n_heads, "num_hidden_layers": 2,
+        "num_key_value_heads": n_kv, "rms_norm_eps": 1e-5,
+        "rope_theta": 5e6, "torch_dtype": dtype, "vocab_size": 384})
+    cfg = fleet.program_config(m, "yi-34b", "test")
+    seed = 2**31 + 77
+    params = fleet.make_params(seed, m, cfg, jax.devices()[0])
+    eng = ServingEngine(cfg, params, max_batch=3, max_seq=64, paged=True,
+                        kv_blocks=12, temperature=0.0)
+    steps = []
+    decode = eng._decode
+
+    def recorded(p, t, c, pos):
+        rids = [r.rid if r is not None else -1 for r in eng.slot_req]
+        logits, c = decode(p, t, c, pos)
+        steps.append((rids, np.asarray(pos), np.asarray(logits, np.float32)))
+        return logits, c
+    eng._decode = recorded
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, m.vocab, n).tolist() for n in (5, 17, 30)]
+    reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    eng.run_until_idle()
+
+    done = [check.Done(prompt=r.prompt, tokens=r.generated, model="gqa")
+            for r in reqs]
+    tokens, pos, seg, rows, _ = check.pack_rows(done, 512)
+    with jax.default_matmul_precision("highest"):
+        want, _ = ref.logits(m, weights.model_key(seed, "gqa"),
+                             jnp.asarray(tokens), jnp.asarray(pos),
+                             jnp.asarray(seg), jnp.asarray(rows))
+    want = np.asarray(want)
+    first, k = {}, 0
+    for r in reqs:
+        first[r.rid] = k
+        k += len(r.generated)
+    worst = 0.0
+    for r in reqs:
+        # the first token: the engine's prefill at its pow-2 bucket
+        plen = len(r.prompt)
+        bucket = 1 << max(plen - 1, 1).bit_length()
+        got, _ = jax.jit(eng._prefill_cache)(
+            eng.params, {"tokens": jnp.asarray(
+                [r.prompt + [0] * (bucket - plen)], jnp.int32)},
+            jnp.int32(plen - 1))
+        worst = max(worst, float(np.abs(
+            np.asarray(got[0], np.float32) - want[first[r.rid]]).max()))
+    n_decoded = 0
+    for rids, p, logits in steps:
+        for slot, rid in enumerate(rids):
+            if rid < 0:
+                continue
+            r = next(q for q in reqs if q.rid == rid)
+            i = int(p[slot]) - len(r.prompt) + 1     # token it predicts
+            worst = max(worst, float(np.abs(
+                logits[slot] - want[first[rid] + i]).max()))
+            n_decoded += 1
+    assert n_decoded == sum(len(r.generated) - 1 for r in reqs)
+    # the logits span several units, so the tolerance is a small share
+    assert float(want.std()) > 0.3
+    assert worst <= tol, worst

@@ -149,3 +149,67 @@ def test_yi6b_admission_program_writes_cache_and_pools_in_place(one_chip):
     assert mem.alias_size_in_bytes == nbytes(cache) + nbytes(pools)
     assert mem.temp_size_in_bytes <= alone.temp_size_in_bytes \
         + alone.output_size_in_bytes
+
+
+# the fleet's cloud replica: one 8-layer stage of Yi-34B (56 query heads
+# over 8 KV heads, d 7,168, d_ff 20,480) with the embedding and the head,
+# 16 lanes of 2,144 tokens and a 2,144-page pool, on one v5e
+YI34_STAGE = dataclasses.replace(get_config("yi-34b"), n_layers=8)
+V5E_LIMIT_BYTES = 16.91e9      # what a v5e reports as its bytes_limit
+CLOUD_LANES, CLOUD_SEQ, CLOUD_PAGES = 16, 2144, 2144
+
+
+def _stage_engine_shapes(one_chip):
+    """The stage's engine programs with the shapes of the cloud replica.
+    The engine is built at one lane and one page (its programs depend only
+    on the shapes they are given), so no full-size cache is held here."""
+    from repro.serving import ServingEngine
+    eng = ServingEngine(YI34_STAGE, None, max_batch=1, max_seq=CLOUD_SEQ,
+                        paged=True, kv_blocks=1)
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda s: _sds(s.shape, s.dtype, one_chip), tree)
+    params = placed(M.params_shapes(YI34_STAGE))
+    cache = placed(M.cache_specs(YI34_STAGE, CLOUD_LANES, CLOUD_SEQ))
+    pools = [_sds((CLOUD_PAGES,) + p.shape[1:], p.dtype, one_chip)
+             for p in eng.kv.pools]
+    return eng, params, cache, pools
+
+
+def _nbytes(tree):
+    return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def test_yi34b_stage_decode_step_fits_one_v5e(one_chip):
+    """The cloud replica's decode program at every published width of
+    Yi-34B: weights (10.76e9 bytes), cache, its undonated cache output and
+    scratch, with the page pool resident beside them, fit one chip."""
+    eng, params, cache, pools = _stage_engine_shapes(one_chip)
+    mem = eng._decode.lower(
+        params, _sds((CLOUD_LANES, 1), jnp.int32, one_chip), cache,
+        _sds((CLOUD_LANES,), jnp.int32, one_chip)).compile(
+        ).memory_analysis()
+    assert mem.argument_size_in_bytes > 10.7e9
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes
+            + _nbytes(pools))
+    assert need < V5E_LIMIT_BYTES, need
+
+
+def test_yi34b_stage_admission_program_fits_one_v5e(one_chip):
+    """The cloud replica's one-program admission at the longest prompt's
+    bucket (2,048 tokens): batch cache and page pools donated and written
+    in place, and the program fits one chip."""
+    eng, params, cache, pools = _stage_engine_shapes(one_chip)
+    i32, bucket = jnp.int32, 2048
+    mem = eng._admit_prompt.lower(
+        params, cache, pools, {"tokens": _sds((1, bucket), i32, one_chip)},
+        _sds((), i32, one_chip), _sds((), i32, one_chip),
+        _sds((bucket // 16,), i32, one_chip),
+        _sds((), jax.random.key(0).dtype, one_chip)).compile(
+        ).memory_analysis()
+    assert mem.alias_size_in_bytes == _nbytes(cache) + _nbytes(pools)
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert need < V5E_LIMIT_BYTES, need
